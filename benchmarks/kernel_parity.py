@@ -1,26 +1,19 @@
-"""Kernel dispatch parity smoke: ref oracles vs Pallas interpret mode.
+"""Kernel parity smoke: the Pallas kernels (interpret mode) vs the oracles.
 
-Off-TPU the engine's hot loops run the ``ref.py`` jnp oracles; the Pallas
-programs (what a real TPU executes as Mosaic) are validated against those
-oracles here via the interpreter, over a small shape sweep per kernel.
-Also asserts the dispatch contract: off-TPU the default mode is ``ref``
-and ``NAVIS_KERNEL_INTERPRET=1`` flips it to ``interpret`` — no off-TPU
-code path may run the (orders-of-magnitude slower) interpreter unless the
-flag is set.
+The engine runs the ``ref.py`` jnp ops on every backend; the three Pallas
+kernels are standalone code, validated here against those oracles via the
+interpreter over a small shape sweep per kernel.
 
 Writes ``experiments/kernels/parity.json``; exits non-zero on any
 mismatch.  Wired into ``scripts/ci.sh``.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from benchmarks import common as Cm
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.kernels.pq_adc import adc_distance_pallas
 from repro.kernels.rerank_l2 import rerank_l2_pallas
 from repro.kernels.topk_pool import pool_merge_pallas
@@ -28,37 +21,9 @@ from repro.kernels.topk_pool import pool_merge_pallas
 KEY = jax.random.PRNGKey(3)
 
 
-def _check_dispatch() -> dict:
-    """The mode contract (trace-time env read)."""
-    on_tpu = jax.default_backend() == "tpu"
-    saved = os.environ.pop("NAVIS_KERNEL_INTERPRET", None)
-    try:
-        default_mode = ops.kernel_mode()
-        os.environ["NAVIS_KERNEL_INTERPRET"] = "1"
-        flagged_mode = ops.kernel_mode()
-    finally:
-        os.environ.pop("NAVIS_KERNEL_INTERPRET", None)
-        if saved is not None:
-            os.environ["NAVIS_KERNEL_INTERPRET"] = saved
-    # explicit raises, not asserts: this is a CI gate and must survive -O
-    if on_tpu:
-        if not (default_mode == flagged_mode == "mosaic"):
-            raise SystemExit(f"TPU dispatch broken: {default_mode}/"
-                             f"{flagged_mode}")
-    elif default_mode != "ref":
-        raise SystemExit(f"off-TPU default mode must be 'ref', got "
-                         f"{default_mode!r} — the engine would run the "
-                         f"Pallas interpreter on every hop")
-    elif flagged_mode != "interpret":
-        raise SystemExit(f"NAVIS_KERNEL_INTERPRET=1 must select "
-                         f"'interpret', got {flagged_mode!r}")
-    return {"backend": jax.default_backend(), "default_mode": default_mode,
-            "flagged_mode": flagged_mode}
-
-
 def run() -> list[str]:
     rows = []
-    blob = {"dispatch": _check_dispatch(), "kernels": {}}
+    blob = {"kernels": {}}
 
     cases = []
     for m, b in ((8, 33), (32, 256), (96, 500)):

@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 MODULES = [
     ("fig3_interference", "benchmarks.interference"),
     ("fig4_wasted_io", "benchmarks.wasted_io"),
@@ -34,6 +36,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None,
                     help="substring filter on module name")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     failures = 0
     for name, modpath in MODULES:

@@ -1,5 +1,5 @@
 #!/usr/bin/env sh
-# Tier-1 CI: CPU-only, offline, collection-strict.
+# Tier-1 CI: CPU-only (JAX_PLATFORMS=cpu), offline, collection-strict.
 #
 # Fails on the first error *including* module collection errors (a module
 # that fails to import is a hard failure, not a skip) — pytest exits
@@ -13,6 +13,7 @@
 # experiments/concurrent/fig11.json).
 set -eu
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu
 
 python -m pytest --collect-only -q >/dev/null   # collection gate
 python -m pytest --strict-markers -q "$@"
@@ -20,9 +21,9 @@ python -m pytest --strict-markers -q "$@"
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
     python -m benchmarks.concurrent --smoke
 
-# Kernel dispatch parity (interpret-mode Pallas vs the jnp oracles the
-# off-TPU engine runs) + traversal-state scaling (hashed visited sets must
-# be flat in n_max); both exit non-zero on violation.
+# Kernel parity (the standalone Pallas kernels in interpret mode vs the
+# jnp ops the engine runs) + traversal-state scaling (hashed visited sets
+# must be flat in n_max); both exit non-zero on violation.
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
     python -m benchmarks.kernel_parity
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \

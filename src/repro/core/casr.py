@@ -24,7 +24,7 @@ from jax import lax
 
 from repro.core.iomodel import IOCounters, PAGE_BYTES
 from repro.core.layout import GraphStore, LayoutSpec
-from repro.kernels import ops as kernel_ops
+from repro.kernels import ref as kernel_ref
 
 INF = jnp.float32(3.4e38)
 
@@ -44,9 +44,9 @@ class CASRResult(NamedTuple):
 def _topk_ids(ids: jax.Array, d: jax.Array, k: int) -> tuple[jax.Array,
                                                              jax.Array]:
     """Smallest-k by d; ties broken by position (stable).  Runs through the
-    kernel-dispatched pool merge (the candidate array is the "pool" prefix
-    merged with its own tail)."""
-    out_d, out_i = kernel_ops.pool_merge(d[:k], ids[:k], d[k:], ids[k:])
+    pool merge (the candidate array is the "pool" prefix merged with its
+    own tail)."""
+    out_d, out_i = kernel_ref.pool_merge_ref(d[:k], ids[:k], d[k:], ids[k:])
     return jnp.where(out_d < INF, out_i, -1), out_d
 
 
@@ -87,7 +87,8 @@ def casr_rerank(store: GraphStore, spec: LayoutSpec, q: jax.Array,
         take = in_group & valid & ~loaded
         n = take.sum()
         counters = _charge_vec_reads(counters, spec, n)
-        d = jnp.where(take, kernel_ops.rerank_l2(q, store.vectors[safe]),
+        d = jnp.where(take,
+                      kernel_ref.rerank_l2_ref(q, store.vectors[safe]),
                       exact_d)
         return d, loaded | take, counters, n
 
@@ -111,14 +112,14 @@ def casr_rerank(store: GraphStore, spec: LayoutSpec, q: jax.Array,
 
     def body(c):
         exact_d, loaded, topk_prev, g, done, rounds, n_loaded, counters = c
-        # speculative next-group I/O (charged even if we converge this round)
-        def spec_load(args):
-            exact_d, loaded, counters, n_loaded = args
-            d, l, ctr, n = load_group(exact_d, loaded, counters, g)
-            return d, l, ctr, n_loaded + n
-        exact_d, loaded, counters, n_loaded = lax.cond(
-            g < max_groups, spec_load,
-            lambda a: a, (exact_d, loaded, counters, n_loaded))
+        # speculative next-group I/O (charged even if we converge this round).
+        # Unconditional: past the last group the group mask is empty and the
+        # load is a no-op.  A ``lax.cond`` here would, under ``vmap``, become
+        # a select whose operands — the closed-over corpus included — are
+        # broadcast to every lane of the wave.
+        exact_d, loaded, counters, n = load_group(exact_d, loaded,
+                                                  counters, g)
+        n_loaded = n_loaded + n
         # convergence test over distances known so far (groups < g)
         known_d = jnp.where(loaded & (jnp.arange(P) < g * s), exact_d, INF)
         topk_new, _ = _topk_ids(pool_ids, known_d, k)
@@ -179,7 +180,7 @@ def casr_stop_point(q: jax.Array, vectors: jax.Array, pool_ids: jax.Array,
     """
     P = pool_ids.shape[0]
     valid = pool_ids >= 0
-    d_all = jnp.where(valid, kernel_ops.rerank_l2(
+    d_all = jnp.where(valid, kernel_ref.rerank_l2_ref(
         q, vectors[jnp.maximum(pool_ids, 0)]), INF)
     max_groups = -(-P // s)
 

@@ -25,16 +25,13 @@ launch/dryrun.py, feeding §Roofline's paper-technique row.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
-from typing import Optional
+import concurrent.futures
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core import engine as engine_mod
 from repro.core import pq as pq_mod
 
@@ -55,24 +52,43 @@ def n_shards(mesh) -> int:
 # ---------------------------------------------------------------------------
 
 def build_sharded_state(engine: engine_mod.Engine, key: jax.Array,
-                        vectors: jax.Array, n_shards_: int):
-    """Range-shard ``vectors`` and build one engine state per shard,
-    stacked on a leading shard axis (host-side, CPU-scale helper).
+                        vectors: jax.Array, mesh):
+    """Range-shard ``vectors`` over every device of ``mesh`` and build each
+    shard's engine state on the device that owns it, all devices at once.
+    The global state (leading shard axis, sharded over every mesh axis) is
+    assembled from those per-device pieces, so no device ever holds
+    another's shard.
 
     One PQ codec is trained on the *global* corpus and installed before
     the per-shard builds — per-shard codecs would make PQ distances (and
     the global top-k merge) incomparable across shards."""
+    devices = list(mesh.devices.flat)
     n = vectors.shape[0]
-    per = n // n_shards_
+    per = n // len(devices)
     sample = vectors[jax.random.choice(
         key, n, (min(n, 4096),), replace=False)]
-    engine.codec = pq_mod.train_pq(key, sample, engine.spec.pq_m)
-    states = []
-    for s in range(n_shards_):
-        st = engine.build(jax.random.fold_in(key, s),
-                          vectors[s * per:(s + 1) * per])
-        states.append(st)
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+    # installed once, before the threads: every build reads it, none
+    # writes it
+    engine.install_codec(pq_mod.train_pq(key, sample, engine.spec.pq_m))
+    parts = [jax.device_put(vectors[s * per:(s + 1) * per], dev)
+             for s, dev in enumerate(devices)]
+
+    def build(s):
+        # one host thread per device, so the shards build concurrently
+        with jax.default_device(devices[s]):
+            return jax.block_until_ready(
+                engine.build(jax.random.fold_in(key, s), parts[s]))
+
+    with concurrent.futures.ThreadPoolExecutor(len(devices)) as pool:
+        states = list(pool.map(build, range(len(devices))))
+    sharding = NamedSharding(mesh, P(db_axes(mesh)))
+
+    def assemble(*xs):
+        return jax.make_array_from_single_device_arrays(
+            (len(xs),) + xs[0].shape, sharding,
+            [jax.device_put(x[None], dev) for x, dev in zip(xs, devices)])
+
+    return jax.tree.map(assemble, *states)
 
 
 def route_inserts(vectors: jax.Array, ids: jax.Array, n_shards_: int,
@@ -120,7 +136,7 @@ def make_sharded_search(engine: engine_mod.Engine, mesh, *,
         # globalise ids: flatten the multi-axis shard index
         flat = jnp.zeros((), jnp.int32)
         for ax in axes:
-            flat = flat * axis_size(ax) + jax.lax.axis_index(ax)
+            flat = flat * lax.axis_size(ax) + lax.axis_index(ax)
         gids = jnp.where(ids >= 0, ids + flat * n_per, -1)
         # merge: gather every shard's (dist, id) pool, reduce locally
         all_d = lax.all_gather(jnp.where(ids >= 0, dists, INF),
@@ -137,7 +153,7 @@ def make_sharded_search(engine: engine_mod.Engine, mesh, *,
         return out_i, -neg, jax.tree.map(lambda x: x[None], state)
 
     spec_state = P(axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_state, P()),              # queries replicated
         out_specs=(P(), P(), spec_state),
@@ -180,7 +196,7 @@ def make_sharded_insert(engine: engine_mod.Engine, mesh, *, bucket: int,
         state, _ = lax.scan(step, state, (vecs, ok))
         return jax.tree.map(lambda x: x[None], state)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes), P(axes), P(axes)),
         out_specs=P(axes),
@@ -195,7 +211,6 @@ def make_sharded_insert(engine: engine_mod.Engine, mesh, *, bucket: int,
 def state_shapes(engine: engine_mod.Engine, n_shards_: int, n_per: int):
     """ShapeDtypeStruct pytree of a stacked sharded state (no allocation)."""
     spec = engine.spec.with_(n_max=n_per)
-    eng = engine_mod.Engine(spec)
     # mirror Engine.build's shapes without computing anything
     from repro.core import cache as cache_mod
     from repro.core import entrance as ent_mod
@@ -205,29 +220,31 @@ def state_shapes(engine: engine_mod.Engine, n_shards_: int, n_per: int):
     def shaped(x):
         return jax.ShapeDtypeStruct((n_shards_,) + x.shape, x.dtype)
 
-    store = empty_store(n_per, spec.dim, spec.r)
-    c_max = max(int(spec.ent_frac * n_per * 2), 64)
-    ent = ent_mod.empty_entrance(c_max, spec.r_ent, n_per)
-    cache = cache_mod.init_cache(store.page_live.shape[0],
-                                 spec.cache_capacity_pages,
-                                 spec.cache_policy, jax.random.PRNGKey(0))
-    state = engine_mod.EngineState(
-        store=store,
-        codes=jnp.zeros((n_per, spec.pq_m), jnp.uint8),
-        ent=ent, cache=cache,
-        tombstone=jnp.zeros((n_per,), bool),
-        default_entries=jnp.zeros((spec.n_entry,), jnp.int32),
-        ctr_search=IOCounters.zeros(), ctr_insert=IOCounters.zeros(),
-        buf_vecs=jnp.zeros((spec.buffer_max, spec.dim), jnp.float32),
-        buf_count=jnp.zeros((), jnp.int32),
-        n_deleted=jnp.zeros((), jnp.int32),
-        free_list=jnp.full((n_per,), -1, jnp.int32),
-        free_count=jnp.zeros((), jnp.int32),
-        free_mask=jnp.zeros((n_per,), bool),
-        maint_cursor=jnp.zeros((), jnp.int32),
-        young_mask=jnp.zeros((n_per,), bool),
-        ctr_maint=IOCounters.zeros())
-    return jax.tree.map(shaped, state)
+    def empty_state():
+        store = empty_store(n_per, spec.dim, spec.r)
+        c_max = max(int(spec.ent_frac * n_per * 2), 64)
+        ent = ent_mod.empty_entrance(c_max, spec.r_ent, n_per)
+        cache = cache_mod.init_cache(store.page_live.shape[0],
+                                     spec.cache_capacity_pages,
+                                     spec.cache_policy, jax.random.PRNGKey(0))
+        return engine_mod.EngineState(
+            store=store,
+            codes=jnp.zeros((n_per, spec.pq_m), jnp.uint8),
+            ent=ent, cache=cache,
+            tombstone=jnp.zeros((n_per,), bool),
+            default_entries=jnp.zeros((spec.n_entry,), jnp.int32),
+            ctr_search=IOCounters.zeros(), ctr_insert=IOCounters.zeros(),
+            buf_vecs=jnp.zeros((spec.buffer_max, spec.dim), jnp.float32),
+            buf_count=jnp.zeros((), jnp.int32),
+            n_deleted=jnp.zeros((), jnp.int32),
+            free_list=jnp.full((n_per,), -1, jnp.int32),
+            free_count=jnp.zeros((), jnp.int32),
+            free_mask=jnp.zeros((n_per,), bool),
+            maint_cursor=jnp.zeros((), jnp.int32),
+            young_mask=jnp.zeros((n_per,), bool),
+            ctr_maint=IOCounters.zeros())
+
+    return jax.tree.map(shaped, jax.eval_shape(empty_state))
 
 
 def dryrun(engine: engine_mod.Engine, mesh, *, n_per: int = 65_536,

@@ -42,7 +42,7 @@ from repro.core import search as search_mod
 from repro.core.iomodel import (IOCounters, PAGE_BYTES, merge_counters,
                                 sum_counters)
 from repro.core.layout import GraphStore, LayoutSpec
-from repro.kernels import ops as kernel_ops
+from repro.kernels import ref as kernel_ref
 
 INF = jnp.float32(3.4e38)
 
@@ -192,6 +192,12 @@ class Engine:
         self._sym: Optional[jax.Array] = None
         self._jit_ops()
 
+    def install_codec(self, codec: pq_mod.PQCodec):
+        """Set the PQ codec and its symmetric distance tables together;
+        ``build`` keeps a codec installed before it is called."""
+        self.codec = codec
+        self._sym = pq_mod.sym_tables(codec)
+
     def _jit_ops(self):
         self.search = jax.jit(self._search)
         self.insert = jax.jit(self._insert)
@@ -231,8 +237,8 @@ class Engine:
         k_pq, k_ent, k_build = jax.random.split(key, 3)
 
         if shared is not None:
-            self.codec, codes, store0 = shared
-            self._sym = pq_mod.sym_tables(self.codec)
+            codec, codes, store0 = shared
+            self.install_codec(codec)
             from repro.core.layout import assign_initial_pages
             store = assign_initial_pages(store0, spec.lspec)
         else:
@@ -244,8 +250,7 @@ class Engine:
                 sample = base_vectors[
                     jax.random.choice(k_pq, n_base, (min(n_base, 4096),),
                                       replace=False)]
-                self.codec = pq_mod.train_pq(k_pq, sample, spec.pq_m)
-            self._sym = pq_mod.sym_tables(self.codec)
+                self.install_codec(pq_mod.train_pq(k_pq, sample, spec.pq_m))
             codes = jnp.zeros((n_max, spec.pq_m), jnp.uint8)
             codes = codes.at[:n_base].set(pq_mod.encode(self.codec,
                                                         base_vectors))
@@ -394,12 +399,13 @@ class Engine:
     def _merge_buffer_hits(self, state, q, ids, dists):
         spec = self.spec
         bvalid = jnp.arange(spec.buffer_max) < state.buf_count
-        bd = jnp.where(bvalid, kernel_ops.rerank_l2(q, state.buf_vecs), INF)
+        bd = jnp.where(bvalid, kernel_ref.rerank_l2_ref(q, state.buf_vecs),
+                       INF)
         # buffer ids are virtual: n_max + slot (not yet in the graph)
         bids = (state.store.n_max + jnp.arange(spec.buffer_max)).astype(
             jnp.int32)
-        d, i = kernel_ops.pool_merge(jnp.where(ids >= 0, dists, INF), ids,
-                                     bd, bids)
+        d, i = kernel_ref.pool_merge_ref(
+            jnp.where(ids >= 0, dists, INF), ids, bd, bids)
         return jnp.where(d < INF, i, -1), d
 
     # -- insert ---------------------------------------------------------------

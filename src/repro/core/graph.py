@@ -54,7 +54,10 @@ def brute_force_topk(queries: jax.Array, vectors: jax.Array,
         live = jnp.arange(vectors.shape[0]) < n_live
 
     def per_q(q):
-        d = vnorm - 2.0 * (vectors @ q)                        # [N] (+‖q‖²)
+        # f32 at HIGHEST: TPU's DEFAULT runs bf16 passes, which misorder
+        # neighbours whose distances differ by less than the rounding
+        d = vnorm - 2.0 * jnp.dot(vectors, q,
+                                  precision=lax.Precision.HIGHEST)  # [N]
         d = jnp.where(live, d, INF)
         _, idx = lax.top_k(-d, k)
         return idx.astype(jnp.int32)

@@ -146,7 +146,9 @@ def repair_block(store: GraphStore, codes: jax.Array, sym_tables: jax.Array,
                 return cur.at[j].set(
                     jnp.where(dd[best] < INF, cand[best], -1))
 
-            return lax.cond(dead_row[j], do, lambda c: c, cur), None
+            # a select, not a cond: under the block vmap a cond's operands
+            # (the whole edge table among them) are broadcast to every row
+            return jnp.where(dead_row[j], do(cur), cur), None
 
         start_row = jnp.where(dead_row, -1, row)
         out, _ = lax.scan(fill_slot, start_row, jnp.arange(r))

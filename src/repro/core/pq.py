@@ -12,6 +12,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# The codecs are f32: on TPU a DEFAULT-precision f32 matmul runs in bf16
+# passes, which would train, encode and tabulate against rounded vectors.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -43,11 +47,11 @@ def train_pq(key: jax.Array, sample: jax.Array, m: int,
 
     def step(cents, _):
         d2 = (jnp.sum(sub ** 2, -1)[:, :, None]
-              - 2 * jnp.einsum("msd,mkd->msk", sub, cents)
+              - 2 * jnp.einsum("msd,mkd->msk", sub, cents, precision=HIGHEST)
               + jnp.sum(cents ** 2, -1)[:, None, :])          # [M, S, 256]
         assign = jnp.argmin(d2, -1)                           # [M, S]
         onehot = jax.nn.one_hot(assign, 256, dtype=sub.dtype)  # [M, S, 256]
-        sums = jnp.einsum("msk,msd->mkd", onehot, sub)
+        sums = jnp.einsum("msk,msd->mkd", onehot, sub, precision=HIGHEST)
         counts = onehot.sum(1)[..., None]
         new = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), cents)
         return new, None
@@ -56,12 +60,23 @@ def train_pq(key: jax.Array, sample: jax.Array, m: int,
     return PQCodec(codebooks=cents)
 
 
+ENCODE_ROWS = 16_384
+
+
 def encode(codec: PQCodec, x: jax.Array) -> jax.Array:
-    """x: [N, D] -> codes uint8 [N, M]."""
+    """x: [N, D] -> codes uint8 [N, M].
+
+    Rows are encoded ``ENCODE_ROWS`` at a time: run eagerly (as
+    ``Engine.build`` does) one pass over a 1M-vector corpus would
+    materialise 32 GiB of [M, rows, 256] distances at M=32."""
+    if x.shape[0] > ENCODE_ROWS:
+        return jnp.concatenate([encode(codec, x[i:i + ENCODE_ROWS])
+                                for i in range(0, x.shape[0], ENCODE_ROWS)])
     n, d = x.shape
     sub = x.reshape(n, codec.m, codec.dsub).transpose(1, 0, 2)
     d2 = (jnp.sum(sub ** 2, -1)[:, :, None]
-          - 2 * jnp.einsum("mnd,mkd->mnk", sub, codec.codebooks)
+          - 2 * jnp.einsum("mnd,mkd->mnk", sub, codec.codebooks,
+                       precision=HIGHEST)
           + jnp.sum(codec.codebooks ** 2, -1)[:, None, :])
     return jnp.argmin(d2, -1).T.astype(jnp.uint8)             # [N, M]
 
@@ -106,7 +121,7 @@ def sym_tables(codec: PQCodec) -> jax.Array:
     """Cross-centroid distance tables T[m, a, b] = ||c_ma - c_mb||^2."""
     cb = codec.codebooks                                      # [M, 256, dsub]
     d2 = (jnp.sum(cb ** 2, -1)[:, :, None]
-          - 2 * jnp.einsum("mad,mbd->mab", cb, cb)
+          - 2 * jnp.einsum("mad,mbd->mab", cb, cb, precision=HIGHEST)
           + jnp.sum(cb ** 2, -1)[:, None, :])
     return jnp.maximum(d2, 0.0)                               # [M, 256, 256]
 
@@ -122,5 +137,10 @@ def sym_distance(tables: jax.Array, code_a: jax.Array,
 
 
 def sym_distance_matrix(tables: jax.Array, codes: jax.Array) -> jax.Array:
-    """All-pairs symmetric PQ distances for a code set [S, M] -> [S, S]."""
-    return jax.vmap(lambda c: sym_distance(tables, c, codes))(codes)
+    """All-pairs symmetric PQ distances for a code set [S, M] -> [S, S].
+
+    Rows are computed 128 at a time, so the gather temporaries are
+    [128, M, S] rather than [S, M, S] (an entrance of 10k members would
+    otherwise need 12 GiB of them)."""
+    return jax.lax.map(lambda c: sym_distance(tables, c, codes), codes,
+                       batch_size=128)

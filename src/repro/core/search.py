@@ -16,9 +16,8 @@ not ``[n_max]`` bitmaps, so a B-lane fan-out wave costs
 ``O(B·max_hops·beam_width)`` memory instead of ``O(B·n_max)``.  The
 ``visited="bitmap"`` mode keeps the dense reference implementation
 (equivalence tests / ablation).  Per-hop examination compute (ADC
-distances, exact L2, pool merge) routes through the backend-dispatched
-kernel layer (:mod:`repro.kernels.ops`): Pallas Mosaic on TPU, the jnp
-oracles elsewhere.
+distances, exact L2, pool merge) runs the jnp ops of
+:mod:`repro.kernels.ref` on every backend.
 """
 from __future__ import annotations
 
@@ -35,7 +34,7 @@ from repro.core import visited as visited_mod
 from repro.core.entrance import EntranceGraph, empty_entrance  # noqa: F401
 from repro.core.iomodel import IOCounters, PAGE_BYTES
 from repro.core.layout import GraphStore, LayoutSpec
-from repro.kernels import ops as kernel_ops
+from repro.kernels import ref as kernel_ref
 
 INF = jnp.float32(3.4e38)
 
@@ -61,7 +60,7 @@ def entrance_search(ent: EntranceGraph, lut: jax.Array, codes: jax.Array,
     seed = jnp.argmax(live).astype(jnp.int32)[None]
     seed_main = ent.ids[seed]
     seed_d = jnp.where(seed_main >= 0,
-                       kernel_ops.adc_distance(lut, codes[jnp.maximum(
+                       kernel_ref.adc_distance_ref(lut, codes[jnp.maximum(
                            seed_main, 0)]), INF)
 
     pool_idx = jnp.full((pool_size,), -1, jnp.int32).at[0].set(seed[0])
@@ -89,9 +88,9 @@ def entrance_search(ent: EntranceGraph, lut: jax.Array, codes: jax.Array,
             ~in_pool
         main_ids = ent.ids[jnp.maximum(nbrs, 0)]
         d = jnp.where(valid & (main_ids >= 0),
-                      kernel_ops.adc_distance(lut, codes[jnp.maximum(
+                      kernel_ref.adc_distance_ref(lut, codes[jnp.maximum(
                           main_ids, 0)]), INF)
-        pool_d, pool_idx = kernel_ops.pool_merge(
+        pool_d, pool_idx = kernel_ref.pool_merge_ref(
             pool_d, pool_idx, d, jnp.where(valid, nbrs, -1))
         unexp = (pool_idx >= 0) & ~visited_mod.contains(expanded, pool_idx)
         return (pool_idx, pool_d, expanded, unexp, hops + 1)
@@ -340,7 +339,7 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
 
     safe_e = jnp.maximum(entry_ids, 0)
     e_valid = entry_ids >= 0
-    e_d = jnp.where(e_valid, kernel_ops.adc_distance(lut, codes[safe_e]),
+    e_d = jnp.where(e_valid, kernel_ref.adc_distance_ref(lut, codes[safe_e]),
                     INF)
     order = jnp.argsort(e_d)
     pool_ids = jnp.full((pool_size,), -1, jnp.int32)
@@ -406,9 +405,9 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
         keep = jnp.zeros_like(nvalid).at[sort_idx].set(first)
         nvalid = nvalid & keep
         nd = jnp.where(nvalid,
-                       kernel_ops.adc_distance(lut, codes[safe_n]), INF)
+                       kernel_ref.adc_distance_ref(lut, codes[safe_n]), INF)
 
-        pool_d, pool_ids = kernel_ops.pool_merge(
+        pool_d, pool_ids = kernel_ref.pool_merge_ref(
             pool_d, pool_ids, nd, jnp.where(nvalid, nbrs, -1))
         unexp = (pool_ids >= 0) & ~visited_mod.contains(expanded, pool_ids)
         counters = dataclasses.replace(counters, hops=counters.hops + 1)
@@ -492,6 +491,7 @@ def full_rerank(store: GraphStore, spec: LayoutSpec, q: jax.Array,
             counters, visited_overflow=counters.visited_overflow + ovf)
     else:
         vec_loaded = res.vec_loaded
-    d = jnp.where(valid, kernel_ops.rerank_l2(q, store.vectors[safe]), INF)
+    d = jnp.where(valid, kernel_ref.rerank_l2_ref(q, store.vectors[safe]),
+                  INF)
     order = jnp.argsort(d)
     return ids[order][:k], d[order][:k], vec_loaded, counters

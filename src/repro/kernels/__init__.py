@@ -1,16 +1,11 @@
-"""Pallas TPU kernels for the paper's compute hot-spots.
+"""Pallas TPU kernels for the paper's compute hot-spots, and their oracles.
 
-Three kernels, each with a pure-jnp oracle in ref.py and a
-backend-dispatched wrapper in ops.py (TPU → Pallas Mosaic; off-TPU → the
-oracle, with interpret-mode Pallas opt-in via NAVIS_KERNEL_INTERPRET=1):
+The engine runs the pure-jnp ops of ``ref.py`` on every backend.  The
+three Pallas kernels are standalone code, checked against those oracles
+in interpret mode; none compiles for TPU v5e yet, so none is on the
+engine's path:
 
   pq_adc     — ADC LUT distance (traversal's per-hop examination)
   rerank_l2  — grouped exact-L2 rerank = CASR's pipelined compute stage
   topk_pool  — explored-pool merge (partial top-k without sort)
-
-The engine's traversal/rerank hot loops (core/search.py, core/casr.py,
-core/engine.py) call through these wrappers.
 """
-from repro.kernels.ops import adc_distance, pool_merge, rerank_l2
-
-__all__ = ["adc_distance", "pool_merge", "rerank_l2"]

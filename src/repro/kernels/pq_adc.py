@@ -39,7 +39,7 @@ def _adc_kernel(lut_ref, codes_ref, out_ref, *, m: int):
 
 def adc_distance_pallas(lut: jax.Array, codes: jax.Array, *,
                         block_b: int = 256,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """lut: [M, 256] f32; codes: [B, M] uint8 -> [B] f32 distances."""
     m = lut.shape[0]
     b = codes.shape[0]

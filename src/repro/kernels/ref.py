@@ -1,6 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets).
 
-These are also what :mod:`repro.kernels.ops` dispatches to off-TPU, so
+These are also the ops the engine's hot loops run, on every backend, so
 they are *dtype-preserving*: they compute in the input dtype exactly like
 the engine's previous inline jnp (``pq.adc_distance`` / ``pq.exact_l2`` /
 stable ``lax.top_k`` merge) — under x64 the engine's distance math stays

@@ -36,7 +36,7 @@ def _rerank_kernel(q_ref, x_ref, out_ref):
 
 
 def rerank_l2_pallas(q: jax.Array, xs: jax.Array, *, group: int = 8,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """q: [D]; xs: [P, D] candidate vectors (PQ order) -> [P] distances.
 
     ``group`` is CASR's s: one grid step per group, giving the

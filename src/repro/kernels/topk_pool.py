@@ -45,7 +45,7 @@ def _merge_kernel(d_ref, ids_ref, out_d_ref, out_ids_ref, *, p: int):
 
 def pool_merge_pallas(pool_d: jax.Array, pool_ids: jax.Array,
                       new_d: jax.Array, new_ids: jax.Array, *,
-                      interpret: bool = True):
+                      interpret: bool):
     """Merge (pool_d [P], new_d [Q]) keeping the P smallest.
 
     Returns (d [P], ids [P]) ascending, -1-padded like the pool inputs.

@@ -14,18 +14,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.layers import ShardingRules
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    # jax.sharding.AxisType (and make_mesh's axis_types=) only exist on
-    # newer JAX; on 0.4.x every axis is Auto anyway, so omit the kwarg.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with explicit-Auto axes where the API supports it."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    """`jax.make_mesh` with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

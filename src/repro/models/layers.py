@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 # ---------------------------------------------------------------------------
 # Sharding helpers
@@ -605,8 +603,8 @@ def moe_block(params: dict, x: jax.Array, *, n_experts: int, top_k: int,
     # the tensor axis; every expert shard needs all of its tokens, so gather
     # tokens over the tensor axis here (the SP all-gather).
     xf = shard(xf, tspec)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=tspec, check_vma=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=tspec, check_vma=False)
     out = fn(xf, gates, idx, *weights)
     out = out.reshape(B, S, D)
     return shard(out, rules.residual())

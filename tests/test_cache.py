@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # offline container: seeded shim
-    from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import cache as C
 

@@ -1,0 +1,125 @@
+"""Real-width compiles of the main-path programs for one TPU v5e chip.
+
+Nothing runs here: each program is lowered from shapes and compiled for a
+described (not attached) v5e chip, whose compiler refuses what the chip
+would refuse — a program that does not fit its HBM included.  Widths are
+the DEEP1M deployment's (:mod:`repro.deploy`), at n_max ≥ 1M.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU compiler library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import deploy
+from repro.core import distributed as dist
+from repro.core import entrance as ent_mod
+from repro.core import graph as graph_mod
+from repro.core import pq as pq_mod
+
+HBM_BUDGET = 14 * 2 ** 30         # of the 16 GiB a v5e chip holds
+WAVE_SEARCH, WAVE_INSERT = 64, 16
+ENT_MEMBERS = 10_000              # a 1% entrance of a 1M corpus
+BUILD_BLOCK = 64                  # Engine.build's default block
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # the TPU compiler library would otherwise write its logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_MIN_LOG_LEVEL", "3")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:        # noqa: BLE001 — whatever stops it, skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep such compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """The deployment's engine, with a codec trained on a tiny CPU
+    sample: the codec is a compile-time constant, its values do not
+    change what is compiled."""
+    from repro.core import Engine
+    eng = Engine(deploy.spec())
+    key = jax.random.PRNGKey(0)
+    vecs, _ = deploy.corpus(key, 2048)
+    eng.install_codec(pq_mod.train_pq(key, vecs, eng.spec.pq_m))
+    return eng
+
+
+def _programs(eng, one_chip):
+    """name -> (jitted program, argument shapes, static keywords)."""
+    spec = eng.spec
+
+    def shape(s, dtype=None):
+        if not isinstance(s, tuple):
+            s, dtype = s.shape, s.dtype
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    st = jax.tree.map(lambda s: shape(s.shape[1:], s.dtype),
+                      dist.state_shapes(eng, 1, spec.n_max))
+    sym = shape(eng._sym)
+    books = shape(eng.codec.codebooks)
+    i32 = jnp.int32
+    build_kw = dict(e_pos=64, beam_width=4, max_hops=128)
+    link = jax.jit(ent_mod.link_members,
+                   static_argnames=("c_max", "r_ent", "n_max"))
+    return {
+        "search_many": (eng.search_many, (
+            st, shape((WAVE_SEARCH, spec.dim), jnp.float32)), {}),
+        "insert_many": (eng.insert_many, (
+            st, shape((WAVE_INSERT, spec.dim), jnp.float32)), {}),
+        "link_members": (link, (
+            shape((ENT_MEMBERS,), i32), st.codes, sym),
+            dict(c_max=st.ent.c_max, r_ent=spec.r_ent,
+                 n_max=spec.n_max)),
+        "build_block": (graph_mod._build_block, (
+            st.store, spec.lspec, shape((BUILD_BLOCK, spec.dim), jnp.float32),
+            st.codes, sym, books, shape((4,), i32)),
+            dict(alpha=1.0, **build_kw)),
+        "refine_block": (graph_mod._refine_block, (
+            st.store, spec.lspec, shape((BUILD_BLOCK,), i32), st.codes, books,
+            shape((4,), i32)), dict(alpha=1.2, **build_kw)),
+        "repair_block": (eng._repair_block, (
+            st.store, st.codes, sym, st.tombstone, st.cache, st.ctr_maint,
+            shape((), i32)), {}),
+        "finalize_cycle": (eng._finalize_cycle, (
+            st.store, st.tombstone, st.free_list, st.free_count,
+            st.free_mask, st.cache, st.ctr_maint), {}),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "search_many", "insert_many", "link_members", "build_block",
+    "refine_block", "repair_block", "finalize_cycle"])
+def test_compiles_within_hbm(name, engine, one_chip, no_persistent_cache):
+    fn, args, kw = _programs(engine, one_chip)[name]
+    mem = fn.lower(*args, **kw).compile().memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes +
+             mem.output_size_in_bytes)
+    assert total < HBM_BUDGET, (name, mem)

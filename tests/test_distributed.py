@@ -2,7 +2,8 @@
 
 Device count is locked at first jax init, so this runs in a subprocess
 with XLA_FLAGS set (the same pattern as launch/dryrun.py) — never set the
-flag in this process.
+flag in this process.  The subprocesses are pinned to the CPU: they must
+never reach for an accelerator that this process may hold.
 """
 import json
 import os
@@ -35,7 +36,10 @@ _SCRIPT = textwrap.dedent("""
     eng = Engine(spec)
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((4, 2), ("data", "model"))
-    sstate = dist.build_sharded_state(eng, jax.random.PRNGKey(2), vecs, 8)
+    sstate = dist.build_sharded_state(eng, jax.random.PRNGKey(2), vecs, mesh)
+    # each shard was built on its own device, none stacked on device 0
+    assert all(sh.device == d for sh, d in zip(
+        sstate.store.vectors.addressable_shards, mesh.devices.flat))
     fn = dist.make_sharded_search(eng, mesh, n_per=N // 8, n_queries=16)
     with mesh:
         ids, dists, sstate = fn(sstate, queries)
@@ -96,8 +100,7 @@ _MOE_SCRIPT = textwrap.dedent("""
 def test_moe_2d_matches_gather_8dev():
     """The decode-path 2-D expert compute must equal the training gather
     path (capacity set high enough that no tokens drop either way)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _MOE_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600,
                          cwd=os.path.dirname(os.path.dirname(
@@ -109,8 +112,7 @@ def test_moe_2d_matches_gather_8dev():
 
 @pytest.mark.slow
 def test_sharded_search_insert_8dev():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900,
                          cwd=os.path.dirname(os.path.dirname(

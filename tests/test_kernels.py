@@ -4,12 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # offline container: seeded shim
-    from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.kernels.pq_adc import adc_distance_pallas
 from repro.kernels.rerank_l2 import rerank_l2_pallas
 from repro.kernels.topk_pool import pool_merge_pallas
@@ -26,7 +23,7 @@ KEY = jax.random.PRNGKey(7)
 def test_adc_shapes(m, b):
     lut = jax.random.uniform(KEY, (m, 256))
     codes = jax.random.randint(KEY, (b, m), 0, 256).astype(jnp.uint8)
-    got = ops.adc_distance(lut, codes)
+    got = adc_distance_pallas(lut, codes, interpret=True)
     np.testing.assert_allclose(got, ref.adc_distance_ref(lut, codes),
                                rtol=1e-5)
 
@@ -61,7 +58,7 @@ def test_adc_hypothesis(m, b, seed):
 def test_rerank_shapes(d, p, group):
     q = jax.random.normal(KEY, (d,))
     xs = jax.random.normal(jax.random.fold_in(KEY, 1), (p, d))
-    got = ops.rerank_l2(q, xs, group=group)
+    got = rerank_l2_pallas(q, xs, group=group, interpret=True)
     np.testing.assert_allclose(got, ref.rerank_l2_ref(q, xs), rtol=2e-4,
                                atol=2e-3)
 
@@ -69,7 +66,7 @@ def test_rerank_shapes(d, p, group):
 def test_rerank_dtype_bf16_inputs():
     q = jax.random.normal(KEY, (64,)).astype(jnp.bfloat16)
     xs = jax.random.normal(KEY, (33, 64)).astype(jnp.bfloat16)
-    got = ops.rerank_l2(q, xs, group=8)
+    got = rerank_l2_pallas(q, xs, group=8, interpret=True)
     want = ref.rerank_l2_ref(q, xs)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=1e-1)
 
@@ -88,7 +85,7 @@ def test_rerank_hypothesis(p, d, group, seed):
 
 def test_rerank_self_distance_zero():
     xs = jax.random.normal(KEY, (5, 32))
-    got = ops.rerank_l2(xs[2], xs)
+    got = rerank_l2_pallas(xs[2], xs, interpret=True)
     assert float(got[2]) < 1e-4
 
 
@@ -102,7 +99,7 @@ def test_merge_shapes(p, q):
     nd = jax.random.uniform(jax.random.fold_in(KEY, 3), (q,))
     pi = jnp.arange(p, dtype=jnp.int32)
     ni = 10_000 + jnp.arange(q, dtype=jnp.int32)
-    gd, gi = ops.pool_merge(pd, pi, nd, ni)
+    gd, gi = pool_merge_pallas(pd, pi, nd, ni, interpret=True)
     wd, wi = ref.pool_merge_ref(pd, pi, nd, ni)
     np.testing.assert_allclose(gd, wd, rtol=1e-6)
     np.testing.assert_array_equal(gi, wi)
@@ -114,7 +111,7 @@ def test_merge_with_inf_padding():
     pi = jnp.array([5, 6, -1, -1], jnp.int32)
     nd = jnp.array([0.5, 3.0])
     ni = jnp.array([7, 8], jnp.int32)
-    gd, gi = ops.pool_merge(pd, pi, nd, ni)
+    gd, gi = pool_merge_pallas(pd, pi, nd, ni, interpret=True)
     np.testing.assert_array_equal(gi, [7, 5, 6, 8])
 
 

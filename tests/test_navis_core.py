@@ -6,11 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # offline container: seeded shim
-    from _prop import given, settings, st
-
 from repro.core import (Engine, brute_force_topk, check_invariants, preset,
                         recall_at_k, robust_prune)
 from repro.core import casr as casr_mod

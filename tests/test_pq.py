@@ -4,10 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # offline container: seeded shim
-    from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pq
 

@@ -1,5 +1,5 @@
 """O(1)-state traversal: hashed visited sets ≡ bitmap reference, overflow
-saturation semantics, entrance seed guard, kernel dispatch contract."""
+saturation semantics, entrance seed guard, no Pallas on the engine path."""
 import dataclasses
 
 import jax
@@ -13,7 +13,6 @@ from repro.core import search as search_mod
 from repro.core import visited as visited_mod
 from repro.core.entrance import EntranceGraph
 from repro.core.iomodel import IOCounters
-from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(21)
 
@@ -248,35 +247,11 @@ def test_traversal_state_bytes_flat_in_corpus():
     assert hashed[0] < dense[0]
 
 
-def test_kernel_dispatch_default_is_ref_off_tpu(monkeypatch):
-    if jax.default_backend() == "tpu":
-        pytest.skip("dispatch resolves to mosaic on TPU")
-    monkeypatch.delenv("NAVIS_KERNEL_INTERPRET", raising=False)
-    assert ops.kernel_mode() == "ref"
-    monkeypatch.setenv("NAVIS_KERNEL_INTERPRET", "1")
-    assert ops.kernel_mode() == "interpret"
-    monkeypatch.setenv("NAVIS_KERNEL_INTERPRET", "0")
-    assert ops.kernel_mode() == "ref"
-
-
-def test_ops_ref_mode_bit_identical_to_oracles(monkeypatch):
-    if jax.default_backend() == "tpu":
-        pytest.skip("off-TPU contract")
-    monkeypatch.delenv("NAVIS_KERNEL_INTERPRET", raising=False)
-    lut = jax.random.uniform(KEY, (16, 256))
-    codes = jax.random.randint(KEY, (37, 16), 0, 256).astype(jnp.uint8)
-    np.testing.assert_array_equal(np.asarray(ops.adc_distance(lut, codes)),
-                                  np.asarray(ref.adc_distance_ref(lut,
-                                                                  codes)))
-    q = jax.random.normal(KEY, (32,))
-    xs = jax.random.normal(jax.random.fold_in(KEY, 1), (21, 32))
-    np.testing.assert_array_equal(np.asarray(ops.rerank_l2(q, xs)),
-                                  np.asarray(ref.rerank_l2_ref(q, xs)))
-    pd = jax.random.uniform(KEY, (9,))
-    nd = jax.random.uniform(jax.random.fold_in(KEY, 2), (14,))
-    pi = jnp.arange(9, dtype=jnp.int32)
-    ni = 100 + jnp.arange(14, dtype=jnp.int32)
-    gd, gi = ops.pool_merge(pd, pi, nd, ni)
-    wd, wi = ref.pool_merge_ref(pd, pi, nd, ni)
-    np.testing.assert_array_equal(np.asarray(gd), np.asarray(wd))
-    np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+@pytest.mark.parametrize("op", ["search_many", "insert_many"])
+def test_engine_path_has_no_pallas_call(navis, dataset, op):
+    """The engine runs the jnp ops of kernels/ref.py on every backend: no
+    Pallas kernel (and so no interpret mode) is on the fan-out path."""
+    eng, state = navis
+    fn = getattr(eng, "_" + op)
+    jaxpr = jax.make_jaxpr(fn)(state, dataset["queries"][:4])
+    assert "pallas_call" not in str(jaxpr)
