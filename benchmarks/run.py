@@ -3,8 +3,7 @@
 One module per paper table/figure (see DESIGN.md §9); each prints CSV
 rows ``name,key=value,...``.  ``--quick`` shrinks workloads ~2-3×;
 ``--only fig10`` runs a single module.  GVS wall-times come from the SSD
-cost model over exact I/O counters (benchmarks/common.py); the roofline
-module reads the dry-run artifacts in experiments/dryrun/.
+cost model over exact I/O counters (benchmarks/common.py).
 """
 from __future__ import annotations
 
@@ -26,7 +25,6 @@ MODULES = [
     ("fig16_footprint", "benchmarks.footprint"),
     ("fig17_cache_policy", "benchmarks.cache_policy"),
     ("fig18_group_size", "benchmarks.group_size"),
-    ("roofline", "benchmarks.roofline"),
 ]
 
 

@@ -252,6 +252,7 @@ def apply_trace(st: CacheState, trace: jax.Array) -> tuple[jax.Array,
     return hits, st
 
 
+@jax.named_scope("navis.cache_replay")
 def apply_traces(st: CacheState, traces: jax.Array) -> tuple[jax.Array,
                                                              CacheState]:
     """Replay a batch of traces ([Q, T] int32, -1-padded) in wave order.

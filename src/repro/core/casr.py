@@ -65,6 +65,7 @@ def _charge_vec_reads(counters: IOCounters, spec: LayoutSpec,
         **{field: getattr(counters, field) + vec_payload})
 
 
+@jax.named_scope("navis.rerank")
 def casr_rerank(store: GraphStore, spec: LayoutSpec, q: jax.Array,
                 pool_ids: jax.Array, counters: IOCounters, *, k: int,
                 s: int) -> CASRResult:

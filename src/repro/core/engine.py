@@ -20,6 +20,11 @@ against one frozen snapshot — searches end to end, inserts for the
 position-seek phase — and fold the wave's page-access traces back into
 the shared cache; ``insert_many`` then serialises only the conflict-aware
 structural commits.
+
+Each stage of the two fan-outs runs under a ``jax.named_scope`` named
+``navis.<stage>`` (entrance, traverse, rerank, cache_replay, seek, select,
+encode, commit, ...).  The names reach every op's metadata, so a device
+trace attributes time to stages; they change nothing that is compiled.
 """
 from __future__ import annotations
 
@@ -316,7 +321,8 @@ class Engine:
             return state.default_entries, jnp.full(
                 (spec.ent_pool,), -1, jnp.int32)
 
-        return lax.cond(state.ent.count > 0, use_ent, use_default, None)
+        with jax.named_scope("navis.entrance"):
+            return lax.cond(state.ent.count > 0, use_ent, use_default, None)
 
     # -- classification (Fig 4a) --------------------------------------------
 
@@ -845,7 +851,8 @@ class Engine:
             return stats, state
 
         # -- phase ①: concurrent position seek on the frozen snapshot -----
-        new_codes = pq_mod.encode(self.codec, vectors)          # [B, M]
+        with jax.named_scope("navis.encode"):
+            new_codes = pq_mod.encode(self.codec, vectors)      # [B, M]
 
         def seek_one(v):
             ctr0 = IOCounters.zeros()
@@ -865,8 +872,9 @@ class Engine:
             return (seek.nbrs, seek.pool_ids, ctr, seek.hops,
                     seek.rerank_rounds, seek.trace, e_ent)
 
-        nbrs_all, pools, ctrs, hops, rounds, traces, e_ents = \
-            jax.vmap(seek_one)(vectors)
+        with jax.named_scope("navis.seek"):
+            nbrs_all, pools, ctrs, hops, rounds, traces, e_ents = \
+                jax.vmap(seek_one)(vectors)
 
         # padding lanes charge nothing and replay nothing
         ctrs = jax.tree.map(lambda x: jnp.where(ok, x, jnp.zeros_like(x)),
@@ -900,28 +908,31 @@ class Engine:
                 new_id = jnp.where(
                     reuse, free_list[jnp.maximum(free_count - 1, 0)],
                     store.count).astype(jnp.int32)
-                codes = codes.at[new_id].set(code)
-                nbrs2 = insert_mod.revalidate_neighbors(
-                    nbrs, new_id, code, codes, self._sym, tombstone)
-                ctr, _ = insert_mod.charge_rmw_rereads(
-                    IOCounters.zeros(), spec.lspec, store, nbrs2, dirty)
-                sres = insert_mod.commit_insert(
-                    store, spec.lspec, cache, ctr, v, nbrs2, codes,
-                    self._sym, new_id=new_id)
-                cache = sres.cache
-                dirty = insert_mod.mark_dirty_pages(
-                    dirty, sres.store, new_id, nbrs2, sres.modified)
+                with jax.named_scope("navis.link"):
+                    codes = codes.at[new_id].set(code)
+                    nbrs2 = insert_mod.revalidate_neighbors(
+                        nbrs, new_id, code, codes, self._sym, tombstone)
+                    ctr, _ = insert_mod.charge_rmw_rereads(
+                        IOCounters.zeros(), spec.lspec, store, nbrs2, dirty)
+                    sres = insert_mod.commit_insert(
+                        store, spec.lspec, cache, ctr, v, nbrs2, codes,
+                        self._sym, new_id=new_id)
+                    cache = sres.cache
+                    dirty = insert_mod.mark_dirty_pages(
+                        dirty, sres.store, new_id, nbrs2, sres.modified)
                 if spec.entrance == "dynamic":
-                    ent2 = ent_mod.navis_update(
-                        ent, new_id, code, pool, e_ent, sres.store.count,
-                        codes, self._sym, r_ent_frac=spec.ent_frac)
-                    if spec.cache_policy == "navis":
-                        promoted = ent2.count > ent.count
-                        page = sres.store.edge_page[new_id]
-                        cache = lax.cond(
-                            promoted,
-                            lambda c: cache_mod.priority_admit(c, page),
-                            lambda c: c, cache)
+                    with jax.named_scope("navis.entrance_update"):
+                        ent2 = ent_mod.navis_update(
+                            ent, new_id, code, pool, e_ent,
+                            sres.store.count, codes, self._sym,
+                            r_ent_frac=spec.ent_frac)
+                        if spec.cache_policy == "navis":
+                            promoted = ent2.count > ent.count
+                            page = sres.store.edge_page[new_id]
+                            cache = lax.cond(
+                                promoted,
+                                lambda c: cache_mod.priority_admit(c, page),
+                                lambda c: c, cache)
                     ent = ent2
                 tombstone = tombstone.at[new_id].set(False)
                 n_deleted = n_deleted - reuse.astype(jnp.int32)
@@ -942,14 +953,15 @@ class Engine:
                  free_list, free_count, free_mask, n_deleted, young_mask))
             return carry, (ctr, keep & ~can)
 
-        ((store, codes, ent, cache, _, tombstone, free_list, free_count,
-          free_mask, n_deleted, young_mask),
-         (commit_ctrs, dropped)) = lax.scan(
-            commit,
-            (state.store, state.codes, state.ent, cache, dirty0,
-             state.tombstone, state.free_list, state.free_count,
-             state.free_mask, state.n_deleted, state.young_mask),
-            (vectors, nbrs_all, new_codes, pools, e_ents, ok))
+        with jax.named_scope("navis.commit"):
+            ((store, codes, ent, cache, _, tombstone, free_list, free_count,
+              free_mask, n_deleted, young_mask),
+             (commit_ctrs, dropped)) = lax.scan(
+                commit,
+                (state.store, state.codes, state.ent, cache, dirty0,
+                 state.tombstone, state.free_list, state.free_count,
+                 state.free_mask, state.n_deleted, state.young_mask),
+                (vectors, nbrs_all, new_codes, pools, e_ents, ok))
 
         per = merge_counters(ctrs, commit_ctrs)            # [B]-leading
         stats = OpStats(

@@ -54,6 +54,7 @@ INF = jnp.float32(3.4e38)
 # Neighbor selection (paper §5.2-5.3)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("navis.select")
 def select_neighbors(pool_ids: jax.Array, casr_res, r: int) -> jax.Array:
     """Order the pool for wiring: the CASR-loaded close portion ranked by
     exact distance first, then the unloaded remainder in PQ order (shortcut
@@ -69,6 +70,7 @@ def select_neighbors(pool_ids: jax.Array, casr_res, r: int) -> jax.Array:
     return jnp.where(valid[order], pool_ids[order], -1)[:r]
 
 
+@jax.named_scope("navis.select")
 def full_pool_neighbors(pool_ids: jax.Array, r: int) -> jax.Array:
     """Baseline neighbor selection: pool already exact-reranked — take R."""
     return pool_ids[:r]

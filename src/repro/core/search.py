@@ -300,6 +300,7 @@ def empty_page_seen(store: GraphStore, *, visited: str = "hash",
     return ps.bits if visited == "bitmap" else ps
 
 
+@jax.named_scope("navis.traverse")
 def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
                   codes: jax.Array, cache: cache_mod.CacheState,
                   counters: IOCounters, entry_ids: jax.Array, *,
@@ -371,45 +372,54 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
             (pool_ids, pool_d, unexp, expanded, vec_loaded, ps,
              cache_in, counters, hops) = carry
             trace, trace_n = None, None
-        cand_d = jnp.where(unexp, pool_d, INF)
-        # top_k (stable, like argsort) is O(n) selection, not a full sort
-        neg_sel, sel = lax.top_k(-cand_d, beam_width)
-        beam = jnp.where(-neg_sel < INF, pool_ids[sel], -1)
-        beam_valid = beam >= 0
-        expanded = visited_mod.add(expanded, beam, beam_valid)
+        # one hop: navis.merge picks the beam and folds the scored
+        # neighbours back into the pool, navis.fetch reads the beam's
+        # edgelists, navis.score measures the new neighbours
+        with jax.named_scope("navis.merge"):
+            cand_d = jnp.where(unexp, pool_d, INF)
+            # top_k (stable, like argsort) is O(n) selection, not a full sort
+            neg_sel, sel = lax.top_k(-cand_d, beam_width)
+            beam = jnp.where(-neg_sel < INF, pool_ids[sel], -1)
+            beam_valid = beam >= 0
+            expanded = visited_mod.add(expanded, beam, beam_valid)
 
-        edges, cache_out, counters, ps, trace, trace_n = \
-            fetch_edgelists(store, spec, cache_in, counters, ps,
-                            beam, beam_valid, trace, trace_n)
-        if spec.kind == "packed":
-            vec_loaded = visited_mod.add(vec_loaded, beam, beam_valid)
+        with jax.named_scope("navis.fetch"):
+            edges, cache_out, counters, ps, trace, trace_n = \
+                fetch_edgelists(store, spec, cache_in, counters, ps,
+                                beam, beam_valid, trace, trace_n)
+            if spec.kind == "packed":
+                vec_loaded = visited_mod.add(vec_loaded, beam, beam_valid)
 
         # Vamana semantics: the explored pool is a *set* — candidates evicted
         # from it may be re-scored and re-enter later; only expansion is
         # permanent (marking visited-on-scoring would permanently ban evicted
         # near-misses and measurably hurt recall at wide beams).
-        nbrs = edges.reshape(-1)                              # [W*R]
-        safe_n = jnp.maximum(nbrs, 0)
-        in_pool = (nbrs[:, None] == pool_ids[None, :]).any(axis=1)
-        nvalid = (nbrs >= 0) & ~visited_mod.contains(expanded, nbrs) & \
-            ~in_pool
-        # dedupe within the flat neighbor list (first occurrence wins):
-        # sort the W*R keys instead of scattering through an O(n_max)
-        # position table — the stable sort keeps the lowest flat index
-        # first among equal keys, so the same occurrence survives
-        key_ = jnp.where(nvalid, nbrs, jnp.iinfo(jnp.int32).max)
-        sort_idx = jnp.argsort(key_)
-        sorted_key = key_[sort_idx]
-        first = jnp.concatenate([
-            jnp.ones((1,), bool), sorted_key[1:] != sorted_key[:-1]])
-        keep = jnp.zeros_like(nvalid).at[sort_idx].set(first)
-        nvalid = nvalid & keep
-        nd = jnp.where(nvalid,
-                       kernel_ref.adc_distance_ref(lut, codes[safe_n]), INF)
+        with jax.named_scope("navis.score"):
+            nbrs = edges.reshape(-1)                          # [W*R]
+            safe_n = jnp.maximum(nbrs, 0)
+            in_pool = (nbrs[:, None] == pool_ids[None, :]).any(axis=1)
+            nvalid = (nbrs >= 0) & \
+                ~visited_mod.contains(expanded, nbrs) & ~in_pool
+            # dedupe within the flat neighbor list (first occurrence wins):
+            # sort the W*R keys instead of scattering through an O(n_max)
+            # position table — the stable sort keeps the lowest flat index
+            # first among equal keys, so the same occurrence survives
+            key_ = jnp.where(nvalid, nbrs, jnp.iinfo(jnp.int32).max)
+            sort_idx = jnp.argsort(key_)
+            sorted_key = key_[sort_idx]
+            first = jnp.concatenate([
+                jnp.ones((1,), bool), sorted_key[1:] != sorted_key[:-1]])
+            keep = jnp.zeros_like(nvalid).at[sort_idx].set(first)
+            nvalid = nvalid & keep
+            nd = jnp.where(nvalid,
+                           kernel_ref.adc_distance_ref(lut, codes[safe_n]),
+                           INF)
 
-        pool_d, pool_ids = kernel_ref.pool_merge_ref(
-            pool_d, pool_ids, nd, jnp.where(nvalid, nbrs, -1))
-        unexp = (pool_ids >= 0) & ~visited_mod.contains(expanded, pool_ids)
+        with jax.named_scope("navis.merge"):
+            pool_d, pool_ids = kernel_ref.pool_merge_ref(
+                pool_d, pool_ids, nd, jnp.where(nvalid, nbrs, -1))
+            unexp = (pool_ids >= 0) & \
+                ~visited_mod.contains(expanded, pool_ids)
         counters = dataclasses.replace(counters, hops=counters.hops + 1)
         if frozen_cache:
             return (pool_ids, pool_d, unexp, expanded, vec_loaded, ps,
@@ -465,6 +475,7 @@ def traversal_state_bytes(*, n_max: int, p_max: int, pool_size: int,
 # Full-rerank baseline (packed layout: vectors already piggybacked)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("navis.rerank")
 def full_rerank(store: GraphStore, spec: LayoutSpec, q: jax.Array,
                 res: TraverseResult, counters: IOCounters, *, k: int):
     """Exact-rerank every pool candidate (the non-CASR baseline).
