@@ -1,8 +1,9 @@
 """Kernel parity smoke: the Pallas kernels (interpret mode) vs the oracles.
 
-The engine runs the ``ref.py`` jnp ops on every backend; the three Pallas
-kernels are standalone code, validated here against those oracles via the
-interpreter over a small shape sweep per kernel.
+The engine runs the ``ref.py`` jnp ops (the ADC as a one-hot select on
+TPU, a gather elsewhere); the three Pallas kernels are standalone code,
+validated here against those oracles via the interpreter over a small
+shape sweep per kernel.
 
 Writes ``experiments/kernels/parity.json``; exits non-zero on any
 mismatch.  Wired into ``scripts/ci.sh``.

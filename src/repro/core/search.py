@@ -17,7 +17,8 @@ not ``[n_max]`` bitmaps, so a B-lane fan-out wave costs
 ``visited="bitmap"`` mode keeps the dense reference implementation
 (equivalence tests / ablation).  Per-hop examination compute (ADC
 distances, exact L2, pool merge) runs the jnp ops of
-:mod:`repro.kernels.ref` on every backend.
+:mod:`repro.kernels.ref`; the ADC is a one-hot select on TPU and a
+gather elsewhere (:func:`repro.kernels.ref.adc_distance`).
 """
 from __future__ import annotations
 
@@ -60,7 +61,7 @@ def entrance_search(ent: EntranceGraph, lut: jax.Array, codes: jax.Array,
     seed = jnp.argmax(live).astype(jnp.int32)[None]
     seed_main = ent.ids[seed]
     seed_d = jnp.where(seed_main >= 0,
-                       kernel_ref.adc_distance_ref(lut, codes[jnp.maximum(
+                       kernel_ref.adc_distance(lut, codes[jnp.maximum(
                            seed_main, 0)]), INF)
 
     pool_idx = jnp.full((pool_size,), -1, jnp.int32).at[0].set(seed[0])
@@ -88,7 +89,7 @@ def entrance_search(ent: EntranceGraph, lut: jax.Array, codes: jax.Array,
             ~in_pool
         main_ids = ent.ids[jnp.maximum(nbrs, 0)]
         d = jnp.where(valid & (main_ids >= 0),
-                      kernel_ref.adc_distance_ref(lut, codes[jnp.maximum(
+                      kernel_ref.adc_distance(lut, codes[jnp.maximum(
                           main_ids, 0)]), INF)
         pool_d, pool_idx = kernel_ref.pool_merge_ref(
             pool_d, pool_idx, d, jnp.where(valid, nbrs, -1))
@@ -340,7 +341,7 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
 
     safe_e = jnp.maximum(entry_ids, 0)
     e_valid = entry_ids >= 0
-    e_d = jnp.where(e_valid, kernel_ref.adc_distance_ref(lut, codes[safe_e]),
+    e_d = jnp.where(e_valid, kernel_ref.adc_distance(lut, codes[safe_e]),
                     INF)
     order = jnp.argsort(e_d)
     pool_ids = jnp.full((pool_size,), -1, jnp.int32)
@@ -412,7 +413,7 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
             keep = jnp.zeros_like(nvalid).at[sort_idx].set(first)
             nvalid = nvalid & keep
             nd = jnp.where(nvalid,
-                           kernel_ref.adc_distance_ref(lut, codes[safe_n]),
+                           kernel_ref.adc_distance(lut, codes[safe_n]),
                            INF)
 
         with jax.named_scope("navis.merge"):

@@ -13,6 +13,12 @@ executes at full width, with zero gathers.  The LUT (M×256 f32 ≤ 128 KiB
 for M=128) is pinned whole in VMEM; codes stream through in [TB, M] tiles
 via the grid pipeline (block t+1's HBM→VMEM copy overlaps block t's
 compute — automatic double buffering).
+
+The kernel does not compile for v5e, so it is off the engine's path.
+The engine runs the same one-hot formulation in plain jnp on TPU
+(``ref.adc_distance_onehot``, picked by ``ref.adc_distance`` when the
+program is lowered) and the gather ``ref.adc_distance_ref`` elsewhere;
+the gather is this kernel's allclose oracle.
 """
 from __future__ import annotations
 

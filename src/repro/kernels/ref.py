@@ -1,11 +1,14 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets).
 
-These are also the ops the engine's hot loops run, on every backend, so
-they are *dtype-preserving*: they compute in the input dtype exactly like
-the engine's previous inline jnp (``pq.adc_distance`` / ``pq.exact_l2`` /
+These are also the ops the engine's hot loops run, so they are
+*dtype-preserving*: they compute in the input dtype exactly like the
+engine's previous inline jnp (``pq.adc_distance`` / ``pq.exact_l2`` /
 stable ``lax.top_k`` merge) — under x64 the engine's distance math stays
-float64.  The Pallas kernels themselves emit float32 (TPU VPU/MXU
-accumulation dtype); parity checks compare at float32 tolerance.
+float64.  The ADC lookup has two forms, picked by :func:`adc_distance`
+when the program is lowered: a one-hot select over the LUT on TPU, where
+a per-element gather runs serially, and the gather everywhere else.  The
+Pallas kernels themselves emit float32 (TPU VPU/MXU accumulation dtype);
+parity checks compare at float32 tolerance.
 """
 from __future__ import annotations
 
@@ -20,6 +23,27 @@ def adc_distance_ref(lut: jax.Array, codes: jax.Array) -> jax.Array:
     idx = codes.astype(jnp.int32)
     vals = jnp.take_along_axis(lut, idx.T, axis=1)
     return vals.sum(0)
+
+
+def adc_distance_onehot(lut: jax.Array, codes: jax.Array) -> jax.Array:
+    """:func:`adc_distance_ref` without a gather: each code selects its
+    LUT entry by a compare against an iota of 256, and the row of
+    selections is summed.  Exactly one term per subspace is nonzero, so
+    the per-subspace values equal the gather's bit for bit; only the sum
+    over subspaces may round in another order."""
+    idx = codes.astype(jnp.int32).T                           # [M, B]
+    hit = idx[:, None, :] == jnp.arange(256)[None, :, None]   # [M, 256, B]
+    vals = jnp.where(hit, lut[:, :, None], 0).sum(1)          # [M, B]
+    return vals.sum(0)
+
+
+def adc_distance(lut: jax.Array, codes: jax.Array) -> jax.Array:
+    """The engine's ADC, its form picked when the program is lowered:
+    the one-hot select for TPU, whose gather fetches one element at a
+    time; the gather on every other platform, where the one-hot's
+    256-fold work costs more than the lookup."""
+    return jax.lax.platform_dependent(lut, codes, tpu=adc_distance_onehot,
+                                      default=adc_distance_ref)
 
 
 def rerank_l2_ref(q: jax.Array, xs: jax.Array) -> jax.Array:

@@ -10,6 +10,7 @@ process may load the TPU compiler library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,12 +115,39 @@ def _programs(eng, one_chip):
     }
 
 
+@pytest.fixture(scope="module")
+def compiled(engine, one_chip, no_persistent_cache):
+    """name -> the program compiled for the described chip, once."""
+    programs, done = _programs(engine, one_chip), {}
+
+    def get(name):
+        if name not in done:
+            fn, args, kw = programs[name]
+            done[name] = fn.lower(*args, **kw).compile()
+        return done[name]
+    return get
+
+
 @pytest.mark.parametrize("name", [
     "search_many", "insert_many", "link_members", "build_block",
     "refine_block", "repair_block", "finalize_cycle"])
-def test_compiles_within_hbm(name, engine, one_chip, no_persistent_cache):
-    fn, args, kw = _programs(engine, one_chip)[name]
-    mem = fn.lower(*args, **kw).compile().memory_analysis()
+def test_compiles_within_hbm(name, compiled):
+    mem = compiled(name).memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes +
              mem.output_size_in_bytes)
     assert total < HBM_BUDGET, (name, mem)
+
+
+@pytest.mark.parametrize("name,wave", [("search_many", WAVE_SEARCH),
+                                       ("insert_many", WAVE_INSERT)])
+def test_adc_has_no_lut_gather(name, wave, engine, compiled):
+    """On TPU the ADC is a one-hot select: no gather reads the wave's
+    [wave, M, 256] LUTs (a per-element gather runs serially there).  The
+    commit's unbatched [M, 256] rows of ``pq.sym_distance`` are not the
+    ADC and may stay gathers."""
+    text = compiled(name).as_text()
+    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    lut = f"f32[{wave},{engine.spec.pq_m},256]"
+    operands = re.findall(r" gather\(%([\w.\-]+),", text)
+    assert operands, name
+    assert lut not in {shapes.get(op) for op in operands}, name

@@ -50,6 +50,65 @@ def test_adc_hypothesis(m, b, seed):
 
 
 # ---------------------------------------------------------------------------
+# the engine's ADC: one-hot select (TPU) against the gather (elsewhere)
+# ---------------------------------------------------------------------------
+
+def _wave(dtype=jnp.float32):
+    """A search wave at deep96's widths: 64 LUTs of [M=32, 256] and their
+    [B=128, M] candidate codes, with the extreme codes 0 and 255 in it."""
+    k1, k2 = jax.random.split(KEY)
+    luts = jax.random.uniform(k1, (64, 32, 256), dtype, maxval=50.0)
+    codes = jax.random.randint(k2, (64, 128, 32), 0, 256).astype(jnp.uint8)
+    return luts, codes.at[:, 0].set(0).at[:, 1].set(255)
+
+
+def _per_subspace(adc, luts, codes):
+    """[L, M, B]: each subspace's picked LUT value, as ``adc`` gives it
+    when handed that subspace alone."""
+    one = jax.vmap(lambda lut, c: adc(lut[None], c[:, None]),
+                   in_axes=(0, 1))
+    return jax.jit(jax.vmap(one))(luts, codes)
+
+
+def test_adc_onehot_picks_equal_the_gather_bit_for_bit():
+    luts, codes = _wave()
+    np.testing.assert_array_equal(
+        _per_subspace(ref.adc_distance_onehot, luts, codes),
+        _per_subspace(ref.adc_distance_ref, luts, codes))
+
+
+def test_adc_onehot_sums_agree_to_f32_rounding():
+    luts, codes = _wave()
+    got = jax.jit(jax.vmap(ref.adc_distance_onehot))(luts, codes)
+    want = jax.jit(jax.vmap(ref.adc_distance_ref))(luts, codes)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+def test_adc_onehot_keeps_f64_under_x64():
+    with jax.enable_x64(True):
+        luts, codes = _wave(jnp.float64)
+        got = jax.jit(jax.vmap(ref.adc_distance_onehot))(luts[:2],
+                                                         codes[:2])
+        assert got.dtype == jnp.float64
+        np.testing.assert_allclose(
+            got, jax.vmap(ref.adc_distance_ref)(luts[:2], codes[:2]),
+            rtol=1e-12)
+
+
+@pytest.mark.parametrize("platform,gathers", [("cpu", True),
+                                              ("tpu", False)])
+def test_adc_dispatch_picks_the_form_at_lowering(platform, gathers):
+    """Lowered for CPU the engine's ADC is the gather; lowered for TPU
+    it is the one-hot select, with no gather left."""
+    luts, codes = _wave()
+    text = jax.jit(jax.vmap(ref.adc_distance)).trace(luts, codes).lower(
+        lowering_platforms=(platform,)).as_text()
+    assert ("gather" in text) is gathers
+    assert ("iota" in text) is not gathers
+
+
+# ---------------------------------------------------------------------------
 # rerank_l2
 # ---------------------------------------------------------------------------
 

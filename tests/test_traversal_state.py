@@ -249,8 +249,9 @@ def test_traversal_state_bytes_flat_in_corpus():
 
 @pytest.mark.parametrize("op", ["search_many", "insert_many"])
 def test_engine_path_has_no_pallas_call(navis, dataset, op):
-    """The engine runs the jnp ops of kernels/ref.py on every backend: no
-    Pallas kernel (and so no interpret mode) is on the fan-out path."""
+    """The engine runs the jnp ops of kernels/ref.py, the TPU's one-hot
+    ADC included: no Pallas kernel (and so no interpret mode) is on the
+    fan-out path."""
     eng, state = navis
     fn = getattr(eng, "_" + op)
     jaxpr = jax.make_jaxpr(fn)(state, dataset["queries"][:4])
