@@ -45,7 +45,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
-from repro import deploy  # noqa: E402
+from repro.deploy import DEEP96, N_BASE  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import Engine, brute_force_topk, recall_at_k  # noqa: E402
 from repro.core import distributed as dist  # noqa: E402
@@ -59,7 +59,7 @@ N_IDENTICAL = 8           # leading queries compared with search_batch
 REPAIR_STEPS = 3
 RECALL_FLOOR = 0.90
 SHARDS = 4
-# The deployment holds deploy.N_BASE = 1M base vectors; a run builds
+# The deployment holds N_BASE = 1M base vectors; a run builds
 # fewer.  Engine.build runs at about 133 vectors/s on one TPU v5e (its
 # insertion and refinement passes wire one vertex at a time), so 1M would
 # take about 2 h against a run's 1200 s; 20k builds in about 150 s.
@@ -108,16 +108,16 @@ def _corpus(seed: int, n: int, n_inserts: int):
     k_data, k_q, k_ins, k_build = jax.random.split(jax.random.PRNGKey(seed),
                                                    4)
     t = time.perf_counter()
-    vecs, cents = deploy.corpus(k_data, n)
-    queries = query_stream(k_q, cents, WAVE_SEARCH, noise=deploy.NOISE)
-    inserts = insert_stream(k_ins, cents, n_inserts, noise=deploy.NOISE)
+    vecs, cents = DEEP96.corpus(k_data, n)
+    queries = query_stream(k_q, cents, WAVE_SEARCH, noise=DEEP96.noise)
+    inserts = insert_stream(k_ins, cents, n_inserts, noise=DEEP96.noise)
     jax.block_until_ready((vecs, queries, inserts))
     log("corpus_s", time.perf_counter() - t)
     return vecs, queries, inserts, k_build
 
 
 def _spec(n: int):
-    spec = deploy.spec(n)
+    spec = DEEP96.spec(n)
     log("spec", {f: getattr(spec, f) for f in (
         "dim", "r", "pq_m", "n_max", "e_search", "e_pos", "max_hops",
         "s_search", "r_ent", "ent_pool", "beam_width", "k")})
@@ -127,8 +127,8 @@ def _spec(n: int):
 def one_chip(n: int, seed: int):
     """The one-chip main path; raises CheckFailed on a wrong result."""
     log("n_base", n)
-    if n < deploy.N_BASE:
-        log("n_cut", f"from {deploy.N_BASE}: {CUT_REASON}")
+    if n < N_BASE:
+        log("n_cut", f"from {N_BASE}: {CUT_REASON}")
     vecs, queries, inserts, k_build = _corpus(seed, n, WAVE_INSERT)
     spec = _spec(n)
     eng = Engine(spec)
@@ -214,8 +214,8 @@ def four_chips(n_per: int, seed: int):
     n = SHARDS * n_per
     log("n_base", n)
     log("n_per_shard", n_per)
-    if n_per < deploy.N_BASE:
-        log("n_cut", f"per shard, from {deploy.N_BASE}: {CUT_REASON}")
+    if n_per < N_BASE:
+        log("n_cut", f"per shard, from {N_BASE}: {CUT_REASON}")
     vecs, queries, inserts, k_build = _corpus(seed, n, SHARDS * WAVE_INSERT)
     spec = _spec(n_per)
     eng = Engine(spec)

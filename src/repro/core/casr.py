@@ -61,6 +61,7 @@ def _charge_vec_reads(counters: IOCounters, spec: LayoutSpec,
     return dataclasses.replace(
         counters,
         read_requests=counters.read_requests + n.astype(jnp.int64),
+        vectors_read=counters.vectors_read + n.astype(jnp.int64),
         pad_bytes_read=counters.pad_bytes_read + pad,
         **{field: getattr(counters, field) + vec_payload})
 

@@ -28,7 +28,7 @@ from repro.core import pq as pq_mod
 from repro.core import search as search_mod
 from repro.core.iomodel import IOCounters
 from repro.core.layout import (GraphStore, LayoutSpec, assign_initial_pages,
-                               empty_store)
+                               defrag_edgelists, empty_store)
 
 INF = jnp.float32(3.4e38)
 
@@ -274,8 +274,23 @@ def build_graph(key: jax.Array, vectors: jax.Array, n: int,
     store = bootstrap_store(vectors, spec, n_max, n_boot)
     entry_ids = jnp.arange(n_entry, dtype=jnp.int32) % n_boot
 
+    # the insertion pass takes ``pages_per_insert`` fresh page ids per
+    # vertex from a bump allocator over ``p_max`` ids (2 n_max): at r 48
+    # (3 a vertex) a corpus that fills n_max would run past them, so the
+    # pass re-packs the edgelists first whenever a block would
+    p_max = store.page_live.shape[0]
+    next_page = int(store.next_page)
+    # each block returns a whole new store; the host waits for the block
+    # before the one it just queued, so at most two are in flight
+    # (unbounded, the queued blocks' stores set the build's peak)
+    behind = None
     pos = n_boot
     while pos < n:
+        if next_page + block * spec.pages_per_insert > p_max:
+            store, _, n_pages = defrag_edgelists(
+                store, jnp.arange(n_max) < pos, spec)
+            next_page = int(n_pages)
+        next_page += block * spec.pages_per_insert
         b = min(block, n - pos)
         block_vecs = vectors[pos:pos + b]
         if b < block:   # pad to the jitted block shape; wire only b of them
@@ -290,6 +305,9 @@ def build_graph(key: jax.Array, vectors: jax.Array, n: int,
         else:
             store = store_full
         pos += b
+        if behind is not None:
+            behind.block_until_ready()
+        behind = store.count
 
     if refine and n > n_boot:
         order = jax.random.permutation(key, n).astype(jnp.int32)
@@ -302,6 +320,9 @@ def build_graph(key: jax.Array, vectors: jax.Array, n: int,
                 store, spec, ids_block, codes, codec.codebooks, entry_ids,
                 e_pos=e_pos, alpha=alpha, beam_width=beam_width,
                 max_hops=max_hops)
+            if behind is not None:
+                behind.block_until_ready()
+            behind = store.count
     return store
 
 

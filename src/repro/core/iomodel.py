@@ -45,6 +45,10 @@ class IOCounters:
     # scored/loaded them, the result mask threw them away) — the churn
     # benchmarks read this to quantify pre-consolidation degradation
     tombstone_skips: jax.Array
+    # full-width vectors an exact rerank read from the decoupled vector
+    # file (CASR's groups, or the whole pool without it); a count of
+    # vectors, which a window cannot wrap as the int32 byte counters can
+    vectors_read: jax.Array
 
     @classmethod
     def zeros(cls) -> "IOCounters":

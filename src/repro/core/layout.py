@@ -95,6 +95,17 @@ class LayoutSpec:
         return max(PAGE_BYTES // self.edgelist_bytes, 1)
 
     @property
+    def pages_per_insert(self) -> int:
+        """Fresh edge pages one insert takes from the bump allocator: a
+        page group for the new vertex (packed), or the new vertex's and its
+        ``r`` neighbours' edgelists moved onto ``ceil((r + 1) /
+        edgelists_per_page)`` pages (decoupled, :func:`relocate_edgelists`:
+        2 at r 32, 3 at r 48)."""
+        if self.kind == "packed":
+            return 1
+        return -(-(self.r + 1) // self.edgelists_per_page)
+
+    @property
     def vector_pages_per_read(self) -> int:
         return -(-self.vector_bytes // PAGE_BYTES)
 

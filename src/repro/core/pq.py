@@ -60,18 +60,28 @@ def train_pq(key: jax.Array, sample: jax.Array, m: int,
     return PQCodec(codebooks=cents)
 
 
-ENCODE_ROWS = 16_384
+# bytes of one [M, rows, 256] f32 distance temporary of ``encode``: 16,384
+# rows at M=32
+ENCODE_BYTES = 512 * 2 ** 20
+
+
+def encode_rows(m: int) -> int:
+    """Rows ``encode`` takes at a time for ``m`` subspaces."""
+    return max(ENCODE_BYTES // (m * 256 * 4), 1)
 
 
 def encode(codec: PQCodec, x: jax.Array) -> jax.Array:
     """x: [N, D] -> codes uint8 [N, M].
 
-    Rows are encoded ``ENCODE_ROWS`` at a time: run eagerly (as
+    Rows are encoded ``encode_rows(M)`` at a time, so each [M, rows, 256]
+    temporary holds ``ENCODE_BYTES`` whatever M is: run eagerly (as
     ``Engine.build`` does) one pass over a 1M-vector corpus would
-    materialise 32 GiB of [M, rows, 256] distances at M=32."""
-    if x.shape[0] > ENCODE_ROWS:
-        return jnp.concatenate([encode(codec, x[i:i + ENCODE_ROWS])
-                                for i in range(0, x.shape[0], ENCODE_ROWS)])
+    materialise 32 GiB of distances at M=32 and 96 GiB at M=96.  A row's
+    code does not depend on the rows encoded with it."""
+    rows = encode_rows(codec.m)
+    if x.shape[0] > rows:
+        return jnp.concatenate([encode(codec, x[i:i + rows])
+                                for i in range(0, x.shape[0], rows)])
     n, d = x.shape
     sub = x.reshape(n, codec.m, codec.dsub).transpose(1, 0, 2)
     d2 = (jnp.sum(sub ** 2, -1)[:, :, None]
@@ -126,9 +136,11 @@ def sym_tables(codec: PQCodec) -> jax.Array:
     return jnp.maximum(d2, 0.0)                               # [M, 256, 256]
 
 
+@jax.named_scope("navis.sym")
 def sym_distance(tables: jax.Array, code_a: jax.Array,
                  code_b: jax.Array) -> jax.Array:
-    """code_a: [M]; code_b: [B, M] -> approx squared L2 [B]."""
+    """code_a: [M]; code_b: [B, M] -> approx squared L2 [B].  Its ops
+    carry the scope ``navis.sym`` under their caller's stage."""
     m = tables.shape[0]
     a = code_a.astype(jnp.int32)                              # [M]
     b = code_b.astype(jnp.int32)                              # [B, M]

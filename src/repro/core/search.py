@@ -494,6 +494,7 @@ def full_rerank(store: GraphStore, spec: LayoutSpec, q: jax.Array,
         counters = dataclasses.replace(
             counters,
             read_requests=counters.read_requests + n_loads,
+            vectors_read=counters.vectors_read + n_loads,
             wasted_vec_bytes_read=counters.wasted_vec_bytes_read +
             n_loads * pages * PAGE_BYTES)
         vec_loaded = visited_mod.add(res.vec_loaded, ids, valid)

@@ -37,11 +37,13 @@ def adc_distance_onehot(lut: jax.Array, codes: jax.Array) -> jax.Array:
     return vals.sum(0)
 
 
+@jax.named_scope("navis.adc")
 def adc_distance(lut: jax.Array, codes: jax.Array) -> jax.Array:
     """The engine's ADC, its form picked when the program is lowered:
     the one-hot select for TPU, whose gather fetches one element at a
     time; the gather on every other platform, where the one-hot's
-    256-fold work costs more than the lookup."""
+    256-fold work costs more than the lookup.  Its ops carry the scope
+    ``navis.adc`` under their caller's stage."""
     return jax.lax.platform_dependent(lut, codes, tpu=adc_distance_onehot,
                                       default=adc_distance_ref)
 

@@ -3,7 +3,9 @@
 Nothing runs here: each program is lowered from shapes and compiled for a
 described (not attached) v5e chip, whose compiler refuses what the chip
 would refuse — a program that does not fit its HBM included.  Widths are
-the DEEP1M deployment's (:mod:`repro.deploy`), at n_max ≥ 1M.
+the deployments' (:mod:`repro.deploy`), at n_max ≥ 1M: DEEP's for every
+main-path program, the 768-d text deployment's for the search, insert and
+build programs.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and every test worker imports
@@ -60,17 +62,26 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def engine():
-    """The deployment's engine, with a codec trained on a tiny CPU
-    sample: the codec is a compile-time constant, its values do not
-    change what is compiled."""
+def _engine(dep):
+    """``dep``'s engine, with a codec trained on a tiny CPU sample: the
+    codec is a compile-time constant, its values do not change what is
+    compiled."""
     from repro.core import Engine
-    eng = Engine(deploy.spec())
+    eng = Engine(dep.spec())
     key = jax.random.PRNGKey(0)
-    vecs, _ = deploy.corpus(key, 2048)
+    vecs, _ = dep.corpus(key, 2048)
     eng.install_codec(pq_mod.train_pq(key, vecs, eng.spec.pq_m))
     return eng
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _engine(deploy.DEEP96)
+
+
+@pytest.fixture(scope="module")
+def text_engine():
+    return _engine(deploy.TEXT768)
 
 
 def _programs(eng, one_chip):
@@ -115,10 +126,9 @@ def _programs(eng, one_chip):
     }
 
 
-@pytest.fixture(scope="module")
-def compiled(engine, one_chip, no_persistent_cache):
+def _compiler(eng, one_chip):
     """name -> the program compiled for the described chip, once."""
-    programs, done = _programs(engine, one_chip), {}
+    programs, done = _programs(eng, one_chip), {}
 
     def get(name):
         if name not in done:
@@ -128,14 +138,38 @@ def compiled(engine, one_chip, no_persistent_cache):
     return get
 
 
+@pytest.fixture(scope="module")
+def compiled(engine, one_chip, no_persistent_cache):
+    return _compiler(engine, one_chip)
+
+
+@pytest.fixture(scope="module")
+def text_compiled(text_engine, one_chip, no_persistent_cache):
+    return _compiler(text_engine, one_chip)
+
+
+def _total_bytes(exe) -> int:
+    mem = exe.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes +
+            mem.output_size_in_bytes)
+
+
+def _gathers_lut(exe, wave: int, pq_m: int) -> bool:
+    """Whether a gather of ``exe`` reads an f32[wave, pq_m, 256] operand:
+    the wave's ADC LUTs."""
+    text = exe.as_text()
+    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    operands = re.findall(r" gather\(%([\w.\-]+),", text)
+    assert operands
+    return f"f32[{wave},{pq_m},256]" in {shapes.get(op) for op in operands}
+
+
 @pytest.mark.parametrize("name", [
     "search_many", "insert_many", "link_members", "build_block",
     "refine_block", "repair_block", "finalize_cycle"])
 def test_compiles_within_hbm(name, compiled):
-    mem = compiled(name).memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes +
-             mem.output_size_in_bytes)
-    assert total < HBM_BUDGET, (name, mem)
+    total = _total_bytes(compiled(name))
+    assert total < HBM_BUDGET, (name, total)
 
 
 @pytest.mark.parametrize("name,wave", [("search_many", WAVE_SEARCH),
@@ -145,9 +179,21 @@ def test_adc_has_no_lut_gather(name, wave, engine, compiled):
     [wave, M, 256] LUTs (a per-element gather runs serially there).  The
     commit's unbatched [M, 256] rows of ``pq.sym_distance`` are not the
     ADC and may stay gathers."""
-    text = compiled(name).as_text()
-    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
-    lut = f"f32[{wave},{engine.spec.pq_m},256]"
-    operands = re.findall(r" gather\(%([\w.\-]+),", text)
-    assert operands, name
-    assert lut not in {shapes.get(op) for op in operands}, name
+    assert not _gathers_lut(compiled(name), wave, engine.spec.pq_m), name
+
+
+@pytest.mark.parametrize("name", [
+    "search_many", "insert_many", "build_block", "refine_block"])
+def test_text768_compiles_within_hbm(name, text_compiled):
+    """768-d, r 48, pq_m 96 and the deployment's search pool, at n_max
+    1,065,536: the index fits one chip whole."""
+    total = _total_bytes(text_compiled(name))
+    assert total < HBM_BUDGET, (name, total)
+
+
+@pytest.mark.parametrize("name,wave", [("search_many", WAVE_SEARCH),
+                                       ("insert_many", WAVE_INSERT)])
+def test_text768_adc_has_no_lut_gather(name, wave, text_engine,
+                                       text_compiled):
+    assert not _gathers_lut(text_compiled(name), wave,
+                            text_engine.spec.pq_m), name

@@ -111,6 +111,7 @@ def test_casr_loads_bounded_and_counted(navis, dataset, s):
         int(cres.n_loaded) * vb
     assert int(cres.counters.read_requests) == int(cres.n_loaded) * \
         spec.lspec.vector_pages_per_read
+    assert int(cres.counters.vectors_read) == int(cres.n_loaded)
 
 
 def test_casr_saves_vector_loads_vs_full(navis, dataset):

@@ -94,3 +94,24 @@ def test_encode_is_nearest_centroid(codec):
         for mm in range(0, cd.m, 5):
             d = jnp.sum((cd.codebooks[mm] - sub[i, mm]) ** 2, -1)
             assert int(codes[i, mm]) == int(jnp.argmin(d))
+
+
+def test_encode_block_holds_its_byte_budget():
+    """Each [M, rows, 256] f32 temporary of ``encode`` holds at most
+    ``ENCODE_BYTES``: 16,384 rows at M=32, a third of that at M=96."""
+    assert pq.encode_rows(32) == 16_384
+    for m in (8, 32, 96, 128):
+        assert m * pq.encode_rows(m) * 256 * 4 <= pq.ENCODE_BYTES
+    assert pq.encode_rows(96) == 5_461
+
+
+@pytest.mark.parametrize("dim,m", [(32, 16), (768, 96)])
+def test_encode_codes_do_not_depend_on_the_block(dim, m, monkeypatch):
+    """Codes encoded in blocks are bit-identical to one pass over every
+    row, the last block short."""
+    x = jax.random.normal(KEY, (300, dim))
+    cd = pq.train_pq(KEY, x, m=m, iters=2)
+    whole = np.asarray(pq.encode(cd, x))
+    monkeypatch.setattr(pq, "ENCODE_BYTES", m * 256 * 4 * 37)
+    assert pq.encode_rows(m) == 37
+    np.testing.assert_array_equal(np.asarray(pq.encode(cd, x)), whole)
