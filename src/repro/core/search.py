@@ -9,10 +9,11 @@ maintained until convergence.
 Everything is jittable: the pool, visited sets, cache state and I/O
 counters thread through a ``lax.while_loop``.
 
-Traversal state is O(1) in the corpus: the ``expanded`` / ``vec_loaded`` /
-``page_seen`` sets are fixed-capacity hash sets bounded by the search
-frontier (``max_hops × beam_width`` marks — see :mod:`repro.core.visited`),
-not ``[n_max]`` bitmaps, so a B-lane fan-out wave costs
+Traversal state is O(1) in the corpus: the pool carries each slot's
+"expanded" flag, and the ``vec_loaded`` / ``page_seen`` sets are
+fixed-capacity hash sets bounded by the search frontier
+(``max_hops × beam_width`` marks — see :mod:`repro.core.visited`), not
+``[n_max]`` bitmaps, so a B-lane fan-out wave costs
 ``O(B·max_hops·beam_width)`` memory instead of ``O(B·n_max)``.  The
 ``visited="bitmap"`` mode keeps the dense reference implementation
 (equivalence tests / ablation).  Per-hop examination compute (ADC
@@ -248,25 +249,27 @@ def make_traversal_state(*, visited: str, pool_size: int, beam_width: int,
                          max_hops: int, n_max: int, p_max: int,
                          visited_capacity: int | None = None,
                          frozen: bool = False):
-    """The per-query traversal state ``disk_traverse`` carries — the ONE
-    place the capacity recipe lives (``traversal_state_bytes`` and the
-    footprint benchmark account the same structures).
+    """The per-query traversal state ``disk_traverse`` carries besides its
+    pool — the ONE place the capacity recipe lives
+    (``traversal_state_bytes`` and the footprint benchmark account the
+    same structures).
 
-    Expansion marks ≤ ``beam_width`` ids/pages per hop for ≤ ``max_hops``
-    hops, so ``max_hops × beam_width`` bounds ``expanded``/``page_seen``
-    exactly; ``vec_loaded`` additionally absorbs ``full_rerank`` marking
-    the surviving pool.  Returns (expanded, vec_loaded, page_seen, trace)
-    — ``trace`` is None unless ``frozen``.
+    Two sets: ``vec_loaded``, the vectors a packed page dragged in, and
+    ``page_seen``, the pages this traversal read.  A hop reads the pages of
+    ≤ ``beam_width`` vertices for ≤ ``max_hops`` hops, so
+    ``max_hops × beam_width`` bounds ``page_seen`` exactly; ``vec_loaded``
+    additionally absorbs ``full_rerank`` marking the surviving pool.  Which
+    vertices were expanded is not a set: the pool carries it as a per-slot
+    flag.  Returns (vec_loaded, page_seen, trace) — ``trace`` is None
+    unless ``frozen``.
     """
     cap = (visited_capacity if visited_capacity is not None
            else max_hops * beam_width)
     if visited == "bitmap":
         sets = (visited_mod.make_dense(n_max),
-                visited_mod.make_dense(n_max),
                 visited_mod.make_dense(p_max))
     else:
-        sets = (visited_mod.make_hash(cap),
-                visited_mod.make_hash(cap + pool_size),
+        sets = (visited_mod.make_hash(cap + pool_size),
                 visited_mod.make_hash(cap))
     trace = (jnp.full((max_hops * beam_width,), -1, jnp.int32)
              if frozen else None)
@@ -294,7 +297,7 @@ def empty_page_seen(store: GraphStore, *, visited: str = "hash",
     """An empty per-query page buffer matching what ``disk_traverse`` would
     create for these parameters (callers that need a structurally matching
     placeholder, e.g. masked branches of an insert)."""
-    _, _, ps, _ = make_traversal_state(
+    _, ps, _ = make_traversal_state(
         visited=visited, pool_size=1, beam_width=beam_width,
         max_hops=max_hops, n_max=store.n_max,
         p_max=store.page_live.shape[0], visited_capacity=visited_capacity)
@@ -328,13 +331,17 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
     on this: ``search_many`` (|E_search| pools) and ``insert_many``'s
     position-seek phase (|E_pos| pools via :func:`insert.position_seek`).
 
-    ``visited="hash"`` (default) bounds per-query state by the frontier:
-    expansion marks at most ``beam_width`` ids per hop, so
+    Which pool entries were expanded is a flag on each pool slot, moved
+    with the slot by the merge (see the comment in the loop), so the
+    traversal keeps no set of expanded ids.
+
+    ``visited="hash"`` (default) bounds the page and vector sets by the
+    frontier: a hop reads the pages of at most ``beam_width`` vertices, so
     ``max_hops × beam_width`` is an exact capacity bound and the hash sets
     behave bit-identically to the ``visited="bitmap"`` reference.
-    ``visited_capacity`` overrides the bound (smaller values saturate: the
-    traversal may re-expand vertices, re-charging I/O — counted in
-    ``counters.visited_overflow`` — but results stay well-formed).
+    ``visited_capacity`` overrides the bound (smaller values saturate: a
+    page may be charged again — counted in ``counters.visited_overflow``
+    — but the pool and hops are those of the exact run).
     """
     n_max = store.n_max
     n_entry = entry_ids.shape[0]
@@ -350,7 +357,7 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
     pool_ids = pool_ids.at[:k].set(
         jnp.where(e_valid[order][:k], entry_ids[order][:k], -1))
     pool_d = pool_d.at[:k].set(e_d[order][:k])
-    expanded, vec_loaded, default_ps, trace0 = make_traversal_state(
+    vec_loaded, default_ps, trace0 = make_traversal_state(
         visited=visited, pool_size=pool_size, beam_width=beam_width,
         max_hops=max_hops, n_max=n_max, p_max=store.page_live.shape[0],
         visited_capacity=visited_capacity, frozen=frozen_cache)
@@ -358,31 +365,34 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
     ovf0 = visited_mod.overflow(ps)
     # each hop charges ≤ beam_width accesses, so the trace never overflows
     trace_n0 = jnp.zeros((), jnp.int32) if frozen_cache else None
-    unexp0 = pool_ids >= 0
+    pool_exp0 = jnp.zeros((pool_size,), bool)
 
     def cond(carry):
-        unexp, hops = carry[2], carry[-1]
-        return (hops < max_hops) & unexp.any()
+        pool_ids, pool_exp, hops = carry[0], carry[2], carry[-1]
+        return (hops < max_hops) & ((pool_ids >= 0) & ~pool_exp).any()
 
     def body(carry):
         if frozen_cache:
-            (pool_ids, pool_d, unexp, expanded, vec_loaded, ps,
+            (pool_ids, pool_d, pool_exp, vec_loaded, ps,
              trace, trace_n, counters, hops) = carry
             cache_in = cache                  # closed-over snapshot
         else:
-            (pool_ids, pool_d, unexp, expanded, vec_loaded, ps,
+            (pool_ids, pool_d, pool_exp, vec_loaded, ps,
              cache_in, counters, hops) = carry
             trace, trace_n = None, None
         # one hop: navis.merge picks the beam and folds the scored
         # neighbours back into the pool, navis.fetch reads the beam's
         # edgelists, navis.score measures the new neighbours
         with jax.named_scope("navis.merge"):
+            unexp = (pool_ids >= 0) & ~pool_exp
             cand_d = jnp.where(unexp, pool_d, INF)
             # top_k (stable, like argsort) is O(n) selection, not a full sort
             neg_sel, sel = lax.top_k(-cand_d, beam_width)
             beam = jnp.where(-neg_sel < INF, pool_ids[sel], -1)
             beam_valid = beam >= 0
-            expanded = visited_mod.add(expanded, beam, beam_valid)
+            # mark by id, not by slot: entry points may repeat an id
+            pool_exp = pool_exp | ((pool_ids >= 0) & (
+                pool_ids[:, None] == beam[None, :]).any(axis=1))
 
         with jax.named_scope("navis.fetch"):
             edges, cache_out, counters, ps, trace, trace_n = \
@@ -391,16 +401,18 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
             if spec.kind == "packed":
                 vec_loaded = visited_mod.add(vec_loaded, beam, beam_valid)
 
-        # Vamana semantics: the explored pool is a *set* — candidates evicted
-        # from it may be re-scored and re-enter later; only expansion is
-        # permanent (marking visited-on-scoring would permanently ban evicted
-        # near-misses and measurably hurt recall at wide beams).
+        # Vamana semantics: the explored pool is a *set* and expansion is
+        # permanent.  "Expanded" is a flag on the pool slot, moved with it
+        # by the merge: an expanded vertex still in the pool is dropped
+        # from the new neighbours by ``in_pool``, and one evicted from the
+        # pool cannot re-enter it, since the pool's worst distance never
+        # rises, its distance is fixed, and the stable merge places pool
+        # entries ahead of new ones.  So no set of expanded ids is kept.
         with jax.named_scope("navis.score"):
             nbrs = edges.reshape(-1)                          # [W*R]
             safe_n = jnp.maximum(nbrs, 0)
             in_pool = (nbrs[:, None] == pool_ids[None, :]).any(axis=1)
-            nvalid = (nbrs >= 0) & \
-                ~visited_mod.contains(expanded, nbrs) & ~in_pool
+            nvalid = (nbrs >= 0) & ~in_pool
             # dedupe within the flat neighbor list (first occurrence wins):
             # sort the W*R keys instead of scattering through an O(n_max)
             # position table — the stable sort keeps the lowest flat index
@@ -417,31 +429,30 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
                            INF)
 
         with jax.named_scope("navis.merge"):
-            pool_d, pool_ids = kernel_ref.pool_merge_ref(
-                pool_d, pool_ids, nd, jnp.where(nvalid, nbrs, -1))
-            unexp = (pool_ids >= 0) & \
-                ~visited_mod.contains(expanded, pool_ids)
+            pool_d, pool_ids, pool_exp = kernel_ref.pool_merge_ref(
+                pool_d, pool_ids, nd, jnp.where(nvalid, nbrs, -1),
+                (pool_exp, jnp.zeros_like(nvalid)))
         counters = dataclasses.replace(counters, hops=counters.hops + 1)
         if frozen_cache:
-            return (pool_ids, pool_d, unexp, expanded, vec_loaded, ps,
+            return (pool_ids, pool_d, pool_exp, vec_loaded, ps,
                     trace, trace_n, counters, hops + 1)
-        return (pool_ids, pool_d, unexp, expanded, vec_loaded, ps,
+        return (pool_ids, pool_d, pool_exp, vec_loaded, ps,
                 cache_out, counters, hops + 1)
 
     if frozen_cache:
-        carry = (pool_ids, pool_d, unexp0, expanded, vec_loaded, ps,
+        carry = (pool_ids, pool_d, pool_exp0, vec_loaded, ps,
                  trace0, trace_n0, counters, jnp.zeros((), jnp.int32))
-        (pool_ids, pool_d, _, expanded, vec_loaded, ps, trace,
+        (pool_ids, pool_d, _, vec_loaded, ps, trace,
          trace_n, counters, hops) = lax.while_loop(cond, body, carry)
         cache_out = cache
     else:
-        carry = (pool_ids, pool_d, unexp0, expanded, vec_loaded, ps,
+        carry = (pool_ids, pool_d, pool_exp0, vec_loaded, ps,
                  cache, counters, jnp.zeros((), jnp.int32))
-        (pool_ids, pool_d, _, expanded, vec_loaded, ps, cache_out,
+        (pool_ids, pool_d, _, vec_loaded, ps, cache_out,
          counters, hops) = lax.while_loop(cond, body, carry)
         trace, trace_n = None, None
-    ovf = (visited_mod.overflow(expanded) + visited_mod.overflow(vec_loaded)
-           + visited_mod.overflow(ps) - ovf0).astype(jnp.int64)
+    ovf = (visited_mod.overflow(vec_loaded) + visited_mod.overflow(ps)
+           - ovf0).astype(jnp.int64)
     counters = dataclasses.replace(
         counters, visited_overflow=counters.visited_overflow + ovf)
     return TraverseResult(pool_ids, pool_d, vec_loaded, hops, cache_out,
@@ -458,9 +469,10 @@ def traversal_state_bytes(*, n_max: int, p_max: int, pool_size: int,
                           visited: str = "hash",
                           frozen: bool = False) -> int:
     """Bytes of per-query traversal state ``disk_traverse`` carries
-    (expanded + vec_loaded + page_seen, + the trace in frozen fan-out
-    mode) — accounted over the very structures :func:`make_traversal_state`
-    hands the traversal, so this cannot drift from the implementation.
+    (vec_loaded + page_seen, + the trace in frozen fan-out mode; the
+    pool and its per-slot flags are not counted) — accounted over the
+    very structures :func:`make_traversal_state` hands the traversal, so
+    this cannot drift from the implementation.
     Pure shape math via ``eval_shape`` — nothing is allocated, so
     million-vector hypotheticals are free."""
     def build():

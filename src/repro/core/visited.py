@@ -1,12 +1,15 @@
 """O(1)-state visited sets for graph traversals.
 
-Every ``disk_traverse`` lane used to carry ``expanded`` / ``vec_loaded``
-bitmaps of shape ``[n_max]`` (plus ``page_seen [p_max]``), so a B-query
-fan-out wave allocated ``B × n_max`` booleans — per-wave memory grew with
-the *corpus*, capping the batch sizes the fan-outs could run.  Real
-on-disk GVS systems bound visited-set state by the search *frontier*: a
-traversal expands at most ``beam_width`` vertices per hop for at most
-``max_hops`` hops, so the number of distinct marks is exactly bounded by
+The sets these hold: in ``search.disk_traverse``, ``page_seen`` (the
+pages a traversal has read) and ``vec_loaded`` (the vectors a packed page
+dragged in); in ``search.entrance_search``, the entrance vertices it has
+expanded.  ``disk_traverse`` keeps no expanded set: its pool carries an
+"expanded" flag per slot.  As ``[n_max]`` / ``[p_max]`` bitmaps these
+would make a B-query fan-out wave allocate ``B × n_max`` booleans —
+per-wave memory growing with the *corpus*.  Real on-disk GVS systems
+bound visited-set state by the search *frontier*: a traversal expands at
+most ``beam_width`` vertices per hop for at most ``max_hops`` hops, so
+the number of distinct marks is exactly bounded by
 ``max_hops × beam_width`` regardless of index size.
 
 :class:`HashVisited` is a fixed-capacity open-addressing (linear-probing)
@@ -17,8 +20,9 @@ truly full** — impossible when the capacity honours the mark bound, which
 makes the hashed traversal bit-identical to the bitmap one.  If a caller
 forces a smaller capacity the set *saturates*: the insert is dropped,
 ``overflow`` increments (surfaced as ``IOCounters.visited_overflow``),
-and a later membership test may miss — the traversal then re-expands the
-vertex, which only re-charges I/O; results are never corrupted.
+and a later membership test may miss — the traversal then charges the
+page again (or re-expands an entrance vertex), which only re-charges I/O
+or burns hops; results are never corrupted.
 
 :class:`DenseVisited` wraps the original ``[n]`` bitmap behind the same
 ``contains`` / ``add`` API — kept as the reference implementation for the

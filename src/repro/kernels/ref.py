@@ -54,10 +54,16 @@ def rerank_l2_ref(q: jax.Array, xs: jax.Array) -> jax.Array:
     return jnp.sum(diff * diff, axis=-1)
 
 
-def pool_merge_ref(pool_d, pool_ids, new_d, new_ids):
-    """Keep the P smallest of the concatenation (stable on ties)."""
+def pool_merge_ref(pool_d, pool_ids, new_d, new_ids, *extra):
+    """Keep the P smallest of the concatenation (stable on ties: pool
+    entries ahead of new ones, each in its own order).
+
+    ``extra`` holds ``(pool_x, new_x)`` pairs of further per-slot operands,
+    each moved with its slot.  One multi-operand sort keyed on the
+    distance carries every operand, so no gather follows it.  Returns
+    ``(d, ids, *xs)``, each cut to P."""
     p = pool_d.shape[0]
-    d = jnp.concatenate([pool_d, new_d])
-    ids = jnp.concatenate([pool_ids, new_ids])
-    order = jnp.argsort(d, stable=True)[:p]
-    return d[order], ids[order]
+    ops = [jnp.concatenate(pair)
+           for pair in ((pool_d, new_d), (pool_ids, new_ids), *extra)]
+    return tuple(o[:p] for o in jax.lax.sort(ops, num_keys=1,
+                                             is_stable=True))

@@ -189,3 +189,53 @@ def test_merge_hypothesis(p, q, seed):
     np.testing.assert_array_equal(gi, wi)
     # result sorted ascending
     assert bool(jnp.all(jnp.diff(gd) >= 0))
+
+
+def _merge_by_argsort(pool_d, pool_ids, new_d, new_ids, *extra):
+    """The merge as an argsort followed by a gather of each operand."""
+    p = pool_d.shape[0]
+    ops = [jnp.concatenate(pair)
+           for pair in ((pool_d, new_d), (pool_ids, new_ids), *extra)]
+    order = jnp.argsort(ops[0], stable=True)[:p]
+    return tuple(o[order] for o in ops)
+
+
+@pytest.mark.parametrize("p,q", [(4, 3), (40, 64), (400, 128),
+                                 (2800, 192)])
+def test_pool_merge_sort_equals_argsort_gather(p, q):
+    """One multi-operand sort gives the argsort-and-gather merge bit for
+    bit: ties inside the pool and between pool and new entries (distances
+    drawn from a few values), INF/-1 padding on both sides, and a bool
+    flag carried with its slot."""
+    k = jax.random.fold_in(KEY, p * 1000 + q)
+    kp, kn, kf, kpad = jax.random.split(k, 4)
+    levels = jnp.float32(0.25) * jnp.arange(max(p // 8, 2))
+    pd = jnp.sort(jax.random.choice(kp, levels, (p,)))
+    nd = jax.random.choice(kn, levels, (q,))
+    pi = jnp.arange(p, dtype=jnp.int32)
+    ni = 100_000 + jnp.arange(q, dtype=jnp.int32)
+    # pad the pool's tail and some new entries with INF / -1
+    n_pad = p // 4
+    pd = pd.at[p - n_pad:].set(ref.INF)
+    pi = pi.at[p - n_pad:].set(-1)
+    npad = jax.random.bernoulli(kpad, 0.3, (q,))
+    nd = jnp.where(npad, ref.INF, nd)
+    ni = jnp.where(npad, -1, ni)
+    pf = jax.random.bernoulli(kf, 0.5, (p,)) & (pi >= 0)
+    nf = jnp.zeros((q,), bool)
+
+    assert jnp.unique(pd).shape[0] < p           # ties inside the pool
+    assert bool(jnp.isin(nd, pd).any())          # ties across pool and new
+    want_d, want_i, want_f = _merge_by_argsort(pd, pi, nd, ni, (pf, nf))
+    got_d, got_i, got_f = ref.pool_merge_ref(pd, pi, nd, ni, (pf, nf))
+    for got, want in ((got_d, want_d), (got_i, want_i), (got_f, want_f)):
+        assert got.dtype == want.dtype and got.shape == (p,)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # without extra operands the merge keeps its two outputs
+    two = ref.pool_merge_ref(pd, pi, nd, ni)
+    assert len(two) == 2
+    np.testing.assert_array_equal(np.asarray(two[1]), np.asarray(want_i))
+    # the flag stays on its id: each kept pool id keeps its own flag
+    flag_of = dict(zip(np.asarray(pi).tolist(), np.asarray(pf).tolist()))
+    for i, f in zip(np.asarray(got_i).tolist(), np.asarray(got_f).tolist()):
+        assert f == flag_of.get(i, False)         # new entries: False
