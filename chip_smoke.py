@@ -50,7 +50,6 @@ from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import Engine, brute_force_topk, recall_at_k  # noqa: E402
 from repro.core import distributed as dist  # noqa: E402
 from repro.data import insert_stream, query_stream  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402
 
 WAVE_SEARCH = 64          # queries per search_many wave
 WAVE_INSERT = 16          # vectors per insert_many wave
@@ -210,7 +209,7 @@ def four_chips(n_per: int, seed: int):
     """The sharded path over SHARDS chips; raises CheckFailed."""
     devices = jax.devices()
     check(len(devices) >= SHARDS, f"{len(devices)} devices < {SHARDS}")
-    mesh = make_mesh((SHARDS,), ("shard",))
+    mesh = dist.make_mesh((SHARDS,), ("shard",))
     n = SHARDS * n_per
     log("n_base", n)
     log("n_per_shard", n_per)

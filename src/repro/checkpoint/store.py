@@ -11,9 +11,8 @@ Commit protocol: write into ``step_X.tmp/``, fsync, rename to ``step_X/``,
 then rewrite ``LATEST`` — a crash at any point leaves either the previous
 checkpoint or a complete new one (``*.tmp`` dirs are garbage-collected on
 the next save).  Elastic remesh: arrays are stored unsharded per leaf, so
-``load_latest`` can re-``device_put`` them under any mesh/sharding — a run
-checkpointed on mesh A restarts on mesh B (see launch/train.py
-``--remesh``)."""
+``load_latest`` can re-``device_put`` them under any mesh/sharding — a
+state checkpointed on mesh A restarts on mesh B."""
 from __future__ import annotations
 
 import json

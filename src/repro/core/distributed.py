@@ -1,4 +1,4 @@
-"""Pod-scale GVS: the engine sharded over the production mesh.
+"""Pod-scale GVS: the engine sharded over a device mesh.
 
 The database is range-sharded over every mesh axis (16×16 single pod =
 256 shards; 2×16×16 = 512): each device owns ``n_per`` vertices with a
@@ -19,9 +19,8 @@ per-shard top-k merge; inserts route to their owning shard by id hash).
   independent graphs, which is how multi-segment deployments (Starling,
   Qdrant) scale writes.
 
-``dryrun()`` lowers + compiles both ops on the production meshes with
-ShapeDtypeStructs (no allocation) — the GVS counterpart of
-launch/dryrun.py, feeding §Roofline's paper-technique row.
+``make_mesh`` builds the mesh the shards live on; ``dryrun()`` lowers +
+compiles both ops on a mesh with ShapeDtypeStructs (no allocation).
 """
 from __future__ import annotations
 
@@ -36,6 +35,12 @@ from repro.core import engine as engine_mod
 from repro.core import pq as pq_mod
 
 INF = jnp.float32(3.4e38)
+
+
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def db_axes(mesh) -> tuple[str, ...]:

@@ -151,7 +151,7 @@ class EngineState:
     def live_mask(self):
         """[N_max] bool — slots holding a live (searchable) vector.  With
         deletions and slot reuse the live set is NOT the count prefix:
-        benchmarks/tests must judge ground truth against this mask."""
+        callers and tests must judge ground truth against this mask."""
         return (jnp.arange(self.store.n_max) < self.store.count) & \
             ~self.tombstone
 

@@ -1,11 +1,10 @@
-"""Two-tier I/O accounting + device cost models.
+"""Two-tier I/O accounting.
 
-The paper's SSD is our *slow tier*; the TPU adaptation maps it onto
-HBM-behind-a-gather (or host DRAM over PCIe for beyond-HBM corpora) — see
-DESIGN.md §2.  Every traversal / rerank / structural-update primitive threads
-an :class:`IOCounters` pytree through, so benchmarks read exact per-category
-byte and request counts; the cost models convert them into time (the paper's
-throughput/latency figures) without needing the physical device.
+The paper's SSD is the *slow tier*; here it is HBM behind a gather, and
+its I/O is counted, never timed.  Every traversal / rerank / structural
+update primitive threads an :class:`IOCounters` pytree through, so a
+caller reads exact per-category byte and request counts: the paper's SSD
+metrics.
 
 Categories follow Fig. 4(a): useful vector, wasted vector, edgelist, padding,
 for both reads and writes, all at 4 KiB page granularity.
@@ -42,8 +41,8 @@ class IOCounters:
     # a saturated traversal may re-expand vertices, re-charging I/O only)
     visited_overflow: jax.Array
     # explored-pool slots wasted on tombstoned vertices (the traversal
-    # scored/loaded them, the result mask threw them away) — the churn
-    # benchmarks read this to quantify pre-consolidation degradation
+    # scored/loaded them, the result mask threw them away): the work
+    # tombstones cost a traversal until a consolidation reclaims them
     tombstone_skips: jax.Array
     # full-width vectors an exact rerank read from the decoupled vector
     # file (CASR's groups, or the whole pool without it); a count of
@@ -78,59 +77,3 @@ def sum_counters(batched: IOCounters) -> IOCounters:
     independently; the device serves the union, so counts simply add."""
     return jax.tree.map(lambda x: x.sum(axis=0), batched)
 
-
-@dataclasses.dataclass(frozen=True)
-class SSDModel:
-    """NVMe cost model (defaults ≈ the paper's Crucial T705 PCIe 5.0).
-
-    time = max(request-bound, bandwidth-bound) under a given queue depth;
-    per-request latency contributes to *latency* metrics, throughput uses the
-    steady-state bound.
-    """
-
-    read_iops: float = 1.40e6          # 4 KiB random read IOPS
-    write_iops: float = 1.10e6
-    read_bw: float = 13.6e9            # B/s sequential
-    write_bw: float = 12.0e9
-    request_latency: float = 55e-6     # s, single 4 KiB random read
-    queue_depth: int = 256
-
-    def read_time(self, requests: float, bytes_: float) -> float:
-        return max(requests / self.read_iops, bytes_ / self.read_bw)
-
-    def write_time(self, requests: float, bytes_: float) -> float:
-        return max(requests / self.write_iops, bytes_ / self.write_bw)
-
-    def op_latency(self, requests: float, bytes_: float,
-                   serial_rounds: float) -> float:
-        """Latency of one logical op whose I/O happens in ``serial_rounds``
-        dependent rounds (graph hops are serial; intra-round I/O overlaps)."""
-        return (serial_rounds * self.request_latency
-                + self.read_time(requests, bytes_))
-
-
-@dataclasses.dataclass(frozen=True)
-class HBMModel:
-    """TPU slow-tier analogue: gathers from HBM (819 GB/s, v5e).
-
-    The per-request term models gather descriptor overhead — tiny, but keeps
-    CASR's request-count-vs-bytes tradeoff meaningful on-TPU (DESIGN.md §2).
-    """
-
-    bw: float = 819e9
-    request_latency: float = 1e-6
-    read_iops: float = 50e6
-    write_iops: float = 50e6
-    read_bw: float = 819e9
-    write_bw: float = 819e9
-    queue_depth: int = 1024
-
-    def read_time(self, requests, bytes_):
-        return max(requests / self.read_iops, bytes_ / self.read_bw)
-
-    def write_time(self, requests, bytes_):
-        return max(requests / self.write_iops, bytes_ / self.write_bw)
-
-    def op_latency(self, requests, bytes_, serial_rounds):
-        return (serial_rounds * self.request_latency
-                + self.read_time(requests, bytes_))

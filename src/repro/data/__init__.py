@@ -1,4 +1,3 @@
-from repro.data.pipeline import (TokenStream, insert_stream, make_clustered,
-                                 query_stream)
+from repro.data.pipeline import insert_stream, make_clustered, query_stream
 
-__all__ = ["TokenStream", "insert_stream", "make_clustered", "query_stream"]
+__all__ = ["insert_stream", "make_clustered", "query_stream"]

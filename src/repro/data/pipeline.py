@@ -1,67 +1,14 @@
-"""Deterministic synthetic data pipelines (LM tokens + GVS vector streams).
+"""Deterministic synthetic vector streams: a clustered corpus, queries
+drawn from its mixture, and insert waves that may drift away from it.
 
-Stateless-by-construction: batch ``t`` of shard ``s`` is a pure function of
-``(seed, t, s)`` via ``jax.random.fold_in``, so
-
-* resuming from a checkpoint replays the exact stream with no iterator
-  state to persist,
-* every data-parallel host generates only its shard (no sharded-file
-  bookkeeping), and
-* a straggling/failed batch can be regenerated idempotently — the
-  straggler path in launch/train.py retries ``make_batch`` with the same
-  (step, shard) and gets bit-identical data.
+Each stream is a pure function of its PRNG key, so a run that keeps its
+keys replays the same corpus, queries and inserts.
 """
 from __future__ import annotations
-
-import dataclasses
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-
-# ---------------------------------------------------------------------------
-# LM token stream
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class TokenStream:
-    """Markov-ish synthetic LM data: structured enough that a model trains
-    (loss strictly decreases), cheap enough to generate on the fly."""
-
-    vocab_size: int
-    seq_len: int
-    batch: int                      # per-shard batch
-    seed: int = 0
-    n_shards: int = 1
-
-    def make_batch(self, step: int, shard: int = 0) -> dict:
-        key = jax.random.fold_in(
-            jax.random.fold_in(jax.random.PRNGKey(self.seed), step), shard)
-        k1, k2 = jax.random.split(key)
-        # low-order structure: tokens follow t[i+1] = (31*t[i] + 17 + n) % V
-        base = jax.random.randint(k1, (self.batch,), 0,
-                                  self.vocab_size, jnp.int32)
-        noise = jax.random.randint(k2, (self.seq_len, self.batch), 0, 7,
-                                   jnp.int32)
-
-        def scan_tok(t, n):
-            nxt = (t * 31 + 17 + n) % self.vocab_size
-            return nxt, nxt
-
-        _, toks = jax.lax.scan(scan_tok, base, noise)        # [S, B]
-        return {"tokens": jnp.swapaxes(toks, 0, 1)}          # [B, S]
-
-    def global_batch(self, step: int) -> dict:
-        """All shards concatenated (single-host runs)."""
-        parts = [self.make_batch(step, s) for s in range(self.n_shards)]
-        return {k: jnp.concatenate([p[k] for p in parts])
-                for k in parts[0]}
-
-
-# ---------------------------------------------------------------------------
-# GVS vector streams
-# ---------------------------------------------------------------------------
 
 def make_clustered(key: jax.Array, n: int, dim: int, *, n_clusters: int = 32,
                    scale: float = 3.0, noise: float = 1.0):

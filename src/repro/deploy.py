@@ -7,11 +7,9 @@ of 0.90 on it.  The vectors are a seeded clustered mixture made on the
 device, in place of the dataset's files.
 
 - ``DEEP96``: DEEP (Babenko & Lempitsky, 2016), 96-d float32 image
-  descriptors; ``benchmarks/common.py``'s ``deep-like`` at CPU scale.  A
-  4 KiB page holds about ten of its vectors.
+  descriptors.  A 4 KiB page holds about ten of its vectors.
 - ``TEXT768``: MS MARCO passages (Bajaj et al., 2016) embedded at
-  BERT-base width, 768-d float32; ``benchmarks/common.py``'s
-  ``fineweb-like`` at CPU scale.  A packed page holds one vector.
+  BERT-base width, 768-d float32.  A packed page holds one vector.
 """
 from __future__ import annotations
 
@@ -52,8 +50,9 @@ class Deployment:
         return vecs, cents
 
 
-# r, pq_m and e_pos are deep-like's.  Raised for recall@10, which on this
-# corpus falls with n (0.39 at 50k vectors with deep-like's settings):
+# r, pq_m and e_pos are those of the CPU-scale DEEP analogue (4,000
+# vectors).  Raised for recall@10, which on this corpus falls with n (0.39
+# at 50k vectors with that analogue's settings):
 # - r_ent 32 -> 128: with 32 out-links the entrance graph leaves some
 #   clusters without in-links, and every query of such a cluster misses
 #   all ten neighbours (ent_pool 32 -> 64 with it: an insert's entrance
@@ -66,16 +65,17 @@ DEEP96 = Deployment(
     settings=dict(r=32, pq_m=32, e_search=400, e_pos=80, max_hops=480,
                   s_search=100, r_ent=128, ent_pool=64))
 
-# r, pq_m and e_pos are fineweb-like's; r_ent and ent_pool are DEEP's.  At
-# 768-d the i.i.d. cluster noise crowds the distances together and PQ
-# orders them poorly, so recall needs a pool that covers a large share of
-# the query's cluster, which grows with the corpus, and an exact rerank of
-# half of it: CASR groups of 200 stop before the true neighbours, deep in
-# the PQ order (recall@10 about 0.80 at any pool at 100,000 vectors).
-# max_hops stops the few lanes that go on past a pool's convergence
-# (e_search / beam_width hops), which would set the wave's time.  The
-# cheapest setting of a sweep at 100,000 vectors under the mixed traffic
-# whose worst miss over five seeds is at most 0.06 (PERF.md).
+# r, pq_m and e_pos are those of the CPU-scale text analogue (3,000
+# vectors); r_ent and ent_pool are DEEP's.  At 768-d the i.i.d. cluster
+# noise crowds the distances together and PQ orders them poorly, so
+# recall needs a pool that covers a large share of the query's cluster,
+# which grows with the corpus, and an exact rerank of half of it: CASR
+# groups of 200 stop before the true neighbours, deep in the PQ order
+# (recall@10 about 0.80 at any pool at 100,000 vectors). max_hops stops
+# the few lanes that go on past a pool's convergence (e_search /
+# beam_width hops), which would set the wave's time.  The cheapest
+# setting of a sweep at 100,000 vectors under the mixed traffic whose
+# worst miss over five seeds is at most 0.06 (PERF.md).
 TEXT768 = Deployment(
     dim=768, noise=1.0,
     settings=dict(r=48, pq_m=96, e_search=2800, e_pos=64, max_hops=750,

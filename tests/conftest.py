@@ -1,8 +1,8 @@
 """Shared fixtures: one small clustered dataset + pre-built engines.
 
 Engine builds are the expensive part of the suite, so the graph bundle
-(codec + codes + edges) is built once and re-paged per engine config —
-the same sharing the benchmarks use.
+(codec + codes + edges) is built once and re-paged per engine config
+(``Engine.build(shared=...)``).
 """
 import jax
 import jax.numpy as jnp

@@ -1,8 +1,7 @@
 """Multi-device GVS: shard_map search/insert on 8 fake CPU devices.
 
 Device count is locked at first jax init, so this runs in a subprocess
-with XLA_FLAGS set (the same pattern as launch/dryrun.py) — never set the
-flag in this process.  The subprocesses are pinned to the CPU: they must
+with XLA_FLAGS set — never set the flag in this process.  The subprocesses are pinned to the CPU: they must
 never reach for an accelerator that this process may hold.
 """
 import json
@@ -11,7 +10,10 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import pytest
+
+from repro.core import distributed as dist
 
 _SCRIPT = textwrap.dedent("""
     import os
@@ -34,8 +36,7 @@ _SCRIPT = textwrap.dedent("""
                   e_pos=40, pq_m=16, cache_capacity_pages=64, max_hops=48,
                   buffer_max=32, ent_frac=0.10)
     eng = Engine(spec)
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((4, 2), ("data", "model"))
+    mesh = dist.make_mesh((4, 2), ("data", "model"))
     sstate = dist.build_sharded_state(eng, jax.random.PRNGKey(2), vecs, mesh)
     # each shard was built on its own device, none stacked on device 0
     assert all(sh.device == d for sh, d in zip(
@@ -57,59 +58,6 @@ _SCRIPT = textwrap.dedent("""
 """)
 
 
-_MOE_SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import json
-    import jax, jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.models import layers as L
-    from repro.launch.mesh import make_mesh
-
-    mesh = make_mesh((4, 2), ("data", "model"))
-    B, S, D, E, F, K = 8, 4, 16, 8, 32, 2
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (B, S, D), jnp.float32)
-    params = {
-        "router": jax.random.normal(jax.random.fold_in(key, 1), (D, E)) * 0.1,
-        "up": jax.random.normal(jax.random.fold_in(key, 2), (E, D, F)) * 0.1,
-        "gate": jax.random.normal(jax.random.fold_in(key, 3), (E, D, F)) * 0.1,
-        "down": jax.random.normal(jax.random.fold_in(key, 4), (E, F, D)) * 0.1,
-    }
-    outs = {}
-    with mesh:
-        for name, gather in (("gather", True), ("two_d", False)):
-            rules = L.ShardingRules(batch="data", tensor="model",
-                                    fsdp="data", moe_gather_weights=gather)
-            xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
-            ps = jax.tree.map(lambda w: jax.device_put(
-                w, NamedSharding(mesh, P("model", "data", None))
-                if w.ndim == 3 else NamedSharding(mesh, P())), params)
-            fn = jax.jit(lambda p, xx, r=rules: L.moe_block(
-                p, xx, n_experts=E, top_k=K, capacity_factor=8.0,
-                activation="silu", glu=True, mesh=mesh, rules=r))
-            outs[name] = np.asarray(fn(ps, xs))
-    err = float(np.abs(outs["gather"] - outs["two_d"]).max())
-    rel = err / max(float(np.abs(outs["gather"]).max()), 1e-9)
-    print(json.dumps({"rel_err": rel}))
-""")
-
-
-@pytest.mark.slow
-def test_moe_2d_matches_gather_8dev():
-    """The decode-path 2-D expert compute must equal the training gather
-    path (capacity set high enough that no tokens drop either way)."""
-    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
-    out = subprocess.run([sys.executable, "-c", _MOE_SCRIPT], env=env,
-                         capture_output=True, text=True, timeout=600,
-                         cwd=os.path.dirname(os.path.dirname(
-                             os.path.abspath(__file__))))
-    assert out.returncode == 0, out.stderr[-3000:]
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    assert res["rel_err"] < 1e-4, res
-
-
 @pytest.mark.slow
 def test_sharded_search_insert_8dev():
     env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
@@ -124,3 +72,11 @@ def test_sharded_search_insert_8dev():
     # recall is bounded by per-shard graph quality on 128 points
     assert res["recall"] >= 0.75, res
     assert sum(res["counts"]) == 1024 + 8
+
+
+def test_make_mesh_one_device():
+    """``make_mesh`` lays its axes over the devices with every axis Auto,
+    so GSPMD may partition along any of them."""
+    mesh = dist.make_mesh((1,), ("shard",))
+    assert dict(mesh.shape) == {"shard": 1}
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)

@@ -215,6 +215,48 @@ def test_churn_does_not_drop_inserts_at_capacity(small, dataset):
     assert np.asarray(stats.dropped).all()
 
 
+def test_churn_smoke_keeps_recall(navis, dataset):
+    """Sixteen cycles that each delete 25 live vertices and insert 25
+    through ``insert_many``, consolidating whenever
+    ``needs_consolidation(lookahead=25)`` fires: no insert drops, every
+    consolidation leaves no live edge to a deleted vertex, the graph
+    stays well formed, and recall@10 against the live set ends within
+    0.01 of the fresh build's."""
+    eng, state = navis
+    qs, batch = dataset["queries"], 25
+
+    def hits(state):
+        """Of the live set's true top-10s, how many a wave finds."""
+        ids, _, _, state = eng.search_many(state, qs)
+        truth = brute_force_topk(qs, state.store.vectors, state.live_mask,
+                                 10)
+        return round(float(recall_at_k(ids, truth)) * truth.size), state
+
+    baseline, state = hits(state)
+    rng = np.random.default_rng(0)
+    dropped = consolidations = 0
+    for c in range(16):
+        live = np.flatnonzero(np.asarray(state.live_mask))
+        victims = rng.choice(live, batch, replace=False).astype(np.int32)
+        state = eng.delete_many(state, jnp.asarray(victims))
+        if bool(eng.needs_consolidation(state, lookahead=batch)):
+            _, state = eng.consolidate(state)
+            consolidations += 1
+            inv = check_invariants(state.store, state.tombstone)
+            assert all(bool(v) for v in inv.values()), (c, inv)
+        wave = insert_stream(jax.random.fold_in(jax.random.PRNGKey(90), c),
+                             dataset["cents"], batch)
+        stats, state = eng.insert_many(state, wave)
+        dropped += int(np.asarray(stats.dropped).sum())
+    assert dropped == 0
+    assert consolidations > 0
+    inv = check_invariants(state.store, state.tombstone)
+    assert all(bool(v) for k, v in inv.items()
+               if k != "no_dead_refs"), inv   # the last deletes still pend
+    final, _ = hits(state)
+    assert final >= baseline - 0.01 * qs.shape[0] * 10, (baseline, final)
+
+
 def test_insert_many_draws_from_free_list(navis, dataset):
     eng, state = navis
     state, victims = _delete_some(eng, state, 5, seed=7)

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import (Engine, brute_force_topk, check_invariants, preset,
-                        recall_at_k, robust_prune)
+                        robust_prune)
 from repro.core import casr as casr_mod
 from repro.core import entrance as ent_mod
 from repro.core import pq as pq_mod
@@ -227,20 +227,6 @@ def test_entrance_update_skipped_above_threshold(dataset, shared_bundle):
 # engine end-to-end
 # ---------------------------------------------------------------------------
 
-def test_navis_recall(navis, dataset):
-    eng, state = navis
-    ids, _, _, _ = eng.search_batch(state, dataset["queries"])
-    r = float(recall_at_k(ids, dataset["truth"]))
-    assert r >= 0.9, r
-
-
-def test_odinann_recall(odinann, dataset):
-    eng, state = odinann
-    ids, _, _, _ = eng.search_batch(state, dataset["queries"])
-    r = float(recall_at_k(ids, dataset["truth"]))
-    assert r >= 0.9, r
-
-
 def test_delete_removes_from_results(navis, dataset):
     eng, state = navis
     q = dataset["queries"][0]
@@ -268,12 +254,3 @@ def test_freshdiskann_buffer_and_merge(freshdiskann, dataset):
     assert int(mstats.write_requests) > 0                 # stream rewrite
     inv = check_invariants(state.store)
     assert all(bool(v) for v in inv.values())
-
-
-def test_counter_categories_are_exclusive(navis, dataset):
-    eng, state = navis
-    c = state.ctr_search
-    total = int(c.total_read_bytes())
-    parts = (int(c.edge_bytes_read) + int(c.useful_vec_bytes_read) +
-             int(c.wasted_vec_bytes_read) + int(c.pad_bytes_read))
-    assert total == parts

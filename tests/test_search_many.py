@@ -1,12 +1,9 @@
-"""Batch-parallel search fan-out: equivalence with the sequential scan,
-counter-sum invariants, trace replay, and the buffered-insert overflow
-regression."""
-import dataclasses
-
+"""Batch-parallel search fan-out: counter-sum invariants, trace replay,
+and the buffered-insert overflow regression (equivalence with the
+sequential scan, per preset, is in test_presets.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.core import Engine, IOCounters, preset
 from repro.core import cache as cache_mod
@@ -15,24 +12,11 @@ from repro.core.iomodel import sum_counters
 
 
 # ---------------------------------------------------------------------------
-# search_many vs search_batch
+# search_many counters and cache
 # ---------------------------------------------------------------------------
 
 def _batch(dataset, n=12):
     return dataset["queries"][:n]
-
-
-@pytest.mark.parametrize("fixture", ["navis", "odinann", "freshdiskann"])
-def test_search_many_matches_sequential(fixture, dataset, request):
-    """vmapped fan-out returns identical top-k (ids AND distances) to the
-    lax.scan state-threading path on a shared snapshot — the cache affects
-    only I/O charging, never results."""
-    eng, state = request.getfixturevalue(fixture)
-    qs = _batch(dataset)
-    ids_s, d_s, _, _ = eng.search_batch(state, qs)
-    ids_m, d_m, _, _ = eng.search_many(state, qs)
-    np.testing.assert_array_equal(np.asarray(ids_s), np.asarray(ids_m))
-    np.testing.assert_allclose(np.asarray(d_s), np.asarray(d_m))
 
 
 def test_search_many_counter_sum_invariant(navis, dataset):
