@@ -12,7 +12,10 @@ All policies are pure functions over a :class:`CacheState` pytree, so they
 run inside jitted search/insert loops.  Lookup is O(1) via a direct-map
 ``status``/``slot_of`` table over page ids; evictions scan only the small
 window (LRU argmin) or probe randomly (frozen region), mirroring the paper's
-"no expensive tracking structures" argument.
+"no expensive tracking structures" argument.  :func:`access` and the trace
+replay take the policy as a static string, so a NAVIS access traces to one
+straight-line step whose table writes are single guarded elements: nothing
+per access reads, selects or copies a whole ``[p_max]`` table.
 """
 from __future__ import annotations
 
@@ -80,73 +83,111 @@ def init_cache(p_max: int, capacity_pages: int, policy: str,
 # NAVIS policy
 # ---------------------------------------------------------------------------
 
+def _put(x: jax.Array, i, v, pred) -> jax.Array:
+    """``x`` with ``x[i] = v`` where ``pred``, else with ``x[i]`` written
+    back: one element write (a dynamic-update-slice), never a select or a
+    copy over the whole of ``x``, so a loop-carried table updates in
+    place."""
+    return x.at[i].set(jnp.where(pred, v, x[i]).astype(x.dtype))
+
+
+def _frozen_victim(key, frozen_pages, frozen_last, clock):
+    """Randomized eviction: draw ``_PROBES`` frozen slots and prefer an
+    empty probe, else the first not-recently-used, else probe 0.
+    Returns (next key, victim slot)."""
+    key, sub = jax.random.split(key)
+    f = frozen_pages.shape[0]
+    # int32 explicitly: under x64 the default int64 probes would downcast
+    # into the int32 slot tables on every traced access (FutureWarning)
+    probes = jax.random.randint(sub, (_PROBES,), 0, f, dtype=jnp.int32)
+    occupied = frozen_pages[probes] >= 0
+    recently = (clock - frozen_last[probes]) < _INUSE_TICKS
+    score = jnp.where(~occupied, 0, jnp.where(~recently, 1, 2))
+    return key, probes[jnp.argmin(score)]
+
+
 def _install_frozen(st: CacheState, page) -> CacheState:
     """Move ``page`` into the frozen region (randomized eviction with
     ``_PROBES`` probes that skip recently-used entries), dropping it from
     the window if it currently sits there."""
-    key, sub = jax.random.split(st.key)
-    f = st.frozen_pages.shape[0]
-    # int32 explicitly: under x64 the default int64 probes would downcast
-    # into the int32 slot tables on every traced access (FutureWarning)
-    probes = jax.random.randint(sub, (_PROBES,), 0, f, dtype=jnp.int32)
-    occupied = st.frozen_pages[probes] >= 0
-    recently = (st.clock - st.frozen_last[probes]) < _INUSE_TICKS
-    # prefer an empty probe, else the first not-recently-used, else probe 0
-    score = jnp.where(~occupied, 0, jnp.where(~recently, 1, 2))
-    victim_slot = probes[jnp.argmin(score)]
-    old = st.frozen_pages[victim_slot]
-    status = st.status
-    slot_of = st.slot_of
-    status = jnp.where(old >= 0, status.at[old].set(NOT_CACHED), status)
-    slot_of = jnp.where(old >= 0, slot_of.at[old].set(-1), slot_of)
-    # remove from window
+    key, slot = _frozen_victim(st.key, st.frozen_pages, st.frozen_last,
+                               st.clock)
+    old = st.frozen_pages[slot]
     in_window = st.status[page] == IN_WINDOW
     wslot = st.slot_of[page]
-    window_pages = jnp.where(in_window,
-                             st.window_pages.at[wslot].set(-1),
-                             st.window_pages)
-    window_last = jnp.where(in_window,
-                            st.window_last.at[wslot].set(-1),
-                            st.window_last)
-    status = status.at[page].set(IN_FROZEN)
-    slot_of = slot_of.at[page].set(victim_slot)
-    frozen_pages = st.frozen_pages.at[victim_slot].set(page)
-    frozen_last = st.frozen_last.at[victim_slot].set(st.clock)
-    fill = st.frozen_fill + jnp.where(old >= 0, 0, 1)
+    status = _put(st.status, old, NOT_CACHED, old >= 0)
+    slot_of = _put(st.slot_of, old, -1, old >= 0)
     return dataclasses.replace(
-        st, status=status, slot_of=slot_of, window_pages=window_pages,
-        window_last=window_last, frozen_pages=frozen_pages,
-        frozen_last=frozen_last, frozen_fill=fill, key=key)
+        st, status=status.at[page].set(IN_FROZEN),
+        slot_of=slot_of.at[page].set(slot),
+        window_pages=_put(st.window_pages, wslot, -1, in_window),
+        window_last=_put(st.window_last, wslot, -1, in_window),
+        frozen_pages=st.frozen_pages.at[slot].set(page),
+        frozen_last=st.frozen_last.at[slot].set(st.clock),
+        frozen_fill=st.frozen_fill + jnp.where(old >= 0, 0, 1), key=key)
 
 
-def _navis_hit_window(st: CacheState, page) -> CacheState:
-    """Second window hit ⇒ promote to frozen (randomized eviction)."""
+def _navis_access(st: CacheState, page) -> tuple[jax.Array, CacheState]:
+    """One NAVIS access as a straight-line step.
+
+    - miss: admit into the window, evicting its LRU entry;
+    - window hit: count it and refresh the entry's stamp; a second hit in
+      the window promotes the page to the frozen region (randomized
+      eviction, :func:`_frozen_victim`);
+    - frozen hit: refresh the in-use stamp (eviction protection, §7).
+
+    Every path's table writes run, each guarded by its path's predicate
+    (:func:`_put`), and the paths share writes: a miss and a promotion
+    each evict at most one page and place ``page`` once.  All reads come
+    before an optimization barrier and all writes after it, so no read of
+    a table outlives a write to it and XLA updates the loop-carried
+    ``[p_max]`` tables in place instead of copying them.  The probe draw
+    sits in a ``lax.cond`` over the key and the ``[F]`` frozen arrays
+    alone: the PRNG key advances only when a page is installed, so the
+    eviction choices follow the same key sequence as when each install
+    was a branch of its own.
+    """
+    clock = st.clock + 1
+    status = st.status[page]
     slot = st.slot_of[page]
-    hits = st.hits.at[page].add(1)
-    window_last = st.window_last.at[slot].set(st.clock)
-    st = dataclasses.replace(st, hits=hits, window_last=window_last)
-    return jax.lax.cond(st.hits[page] >= 2,
-                        lambda s: _install_frozen(s, page), lambda s: s, st)
-
-
-def _navis_miss(st: CacheState, page) -> CacheState:
-    """Admit into the window, evicting the LRU window entry."""
-    # empty slots have last=-1; int32 keeps the x64 scatter cast-free
+    count = st.hits[page] + 1               # window hits after this one
+    hit = status != NOT_CACHED
+    frozen = status == IN_FROZEN
+    miss = ~hit
+    promote = hit & ~frozen & (count >= 2)
+    # empty window slots have last=-1; int32 keeps the x64 scatter
+    # cast-free
     victim = jnp.argmin(st.window_last).astype(jnp.int32)
-    old = st.window_pages[victim]
-    status = st.status
-    slot_of = st.slot_of
-    hits = st.hits
-    status = jnp.where(old >= 0, status.at[old].set(NOT_CACHED), status)
-    slot_of = jnp.where(old >= 0, slot_of.at[old].set(-1), slot_of)
-    hits = jnp.where(old >= 0, hits.at[old].set(0), hits)
-    status = status.at[page].set(IN_WINDOW)
-    slot_of = slot_of.at[page].set(victim)
-    hits = hits.at[page].set(1)
-    return dataclasses.replace(
-        st, status=status, slot_of=slot_of, hits=hits,
-        window_pages=st.window_pages.at[victim].set(page),
-        window_last=st.window_last.at[victim].set(st.clock))
+    key, fslot = jax.lax.cond(
+        promote, _frozen_victim,
+        lambda k, *_: (k, jnp.zeros((), jnp.int32)),
+        st.key, st.frozen_pages, st.frozen_last, clock)
+    # the page that leaves the cache to make room, if any
+    old = jnp.where(miss, st.window_pages[victim], st.frozen_pages[fslot])
+    evict = (miss | promote) & (old >= 0)
+    wslot = jnp.where(miss, victim, slot)
+    (clock, page, slot, count, miss, frozen, promote, victim, fslot, old,
+     evict, wslot), st = jax.lax.optimization_barrier(
+        ((clock, page, slot, count, miss, frozen, promote, victim, fslot,
+          old, evict, wslot), st))
+    placed = miss | promote
+    return hit, dataclasses.replace(
+        st, clock=clock, key=key,
+        status=_put(_put(st.status, old, NOT_CACHED, evict), page,
+                    jnp.where(miss, IN_WINDOW, IN_FROZEN), placed),
+        slot_of=_put(_put(st.slot_of, old, -1, evict), page,
+                     jnp.where(miss, victim, fslot), placed),
+        hits=_put(_put(st.hits, old, 0, miss & evict), page,
+                  jnp.where(miss, 1, count), ~frozen),
+        window_pages=_put(st.window_pages, wslot,
+                          jnp.where(miss, page, -1), placed),
+        window_last=_put(st.window_last, wslot,
+                         jnp.where(promote, -1, clock), ~frozen),
+        frozen_pages=_put(st.frozen_pages, fslot, page, promote),
+        frozen_last=_put(st.frozen_last, jnp.where(frozen, slot, fslot),
+                         clock, frozen | promote),
+        frozen_fill=st.frozen_fill + (promote & (old < 0)).astype(
+            jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -219,14 +260,16 @@ def lookup(st: CacheState, page: jax.Array) -> jax.Array:
     return (st.status[page] != NOT_CACHED) & (st.policy != POLICIES["none"])
 
 
-def apply_trace(st: CacheState, trace: jax.Array) -> tuple[jax.Array,
-                                                           CacheState]:
+def apply_trace(st: CacheState, trace: jax.Array,
+                policy: str) -> tuple[jax.Array, CacheState]:
     """Replay a page-access trace into the cache, returning (replay hit
     count, new state).  The valid entries are a contiguous prefix — the
     traversal appends charged accesses in order — so replay runs a
     dynamic-length loop that stops at the first ``-1``: cost scales with
     the accesses actually charged, not with the (heavily padded)
-    ``max_hops × beam_width`` trace capacity.
+    ``max_hops × beam_width`` trace capacity.  Each iteration is one
+    :func:`access` under the static ``policy``; under ``"navis"`` that is
+    a straight-line step with no op over the ``[p_max]`` page tables.
 
     Concurrent readers share one cache: each runs against the same frozen
     snapshot, then their traces are replayed in order so the merged state
@@ -243,7 +286,7 @@ def apply_trace(st: CacheState, trace: jax.Array) -> tuple[jax.Array,
 
     def body(carry):
         i, hits, st = carry
-        hit, st = access(st, trace[i])
+        hit, st = access(st, trace[i], policy)
         return i + 1, hits + hit.astype(jnp.int32), st
 
     _, hits, st = jax.lax.while_loop(
@@ -253,9 +296,10 @@ def apply_trace(st: CacheState, trace: jax.Array) -> tuple[jax.Array,
 
 
 @jax.named_scope("navis.cache_replay")
-def apply_traces(st: CacheState, traces: jax.Array) -> tuple[jax.Array,
-                                                             CacheState]:
-    """Replay a batch of traces ([Q, T] int32, -1-padded) in wave order.
+def apply_traces(st: CacheState, traces: jax.Array,
+                 policy: str) -> tuple[jax.Array, CacheState]:
+    """Replay a batch of traces ([Q, T] int32, -1-padded) in wave order
+    under the static ``policy`` (the one the cache was built with).
 
     Both fan-out paths use this merge: ``search_many`` replays its query
     wave's traces, ``insert_many`` its position-seek traces (before the
@@ -265,7 +309,7 @@ def apply_traces(st: CacheState, traces: jax.Array) -> tuple[jax.Array,
     """
     def step(carry, trace):
         hits, st = carry
-        h, st = apply_trace(st, trace)
+        h, st = apply_trace(st, trace, policy)
         return (hits + h, st), None
 
     (hits, st), _ = jax.lax.scan(step, (jnp.zeros((), jnp.int32), st),
@@ -273,37 +317,29 @@ def apply_traces(st: CacheState, traces: jax.Array) -> tuple[jax.Array,
     return hits, st
 
 
-def access(st: CacheState, page: jax.Array) -> tuple[jax.Array, CacheState]:
+def access(st: CacheState, page: jax.Array,
+           policy: str) -> tuple[jax.Array, CacheState]:
     """One page access.  Returns (hit: bool, new state).
+
+    ``policy`` is the cache's policy as a static string (the engine's
+    ``EngineSpec.cache_policy``; ``st.policy`` holds the same value,
+    traced), so the policy's step is chosen while tracing.  The NAVIS
+    step (:func:`_navis_access`) is straight-line: every table write is
+    one guarded element write, and no ``lax.cond`` carries the
+    ``[p_max]`` tables, so an access costs a few scalar reads and writes,
+    not a pass over the tables.  The baselines keep their branches.
 
     The caller charges a slow-tier read on a miss.  NAVIS refreshes the
     frozen-region in-use stamp on hits (eviction protection, §7).
     """
+    if policy == "navis":
+        return _navis_access(st, page)
     st = dataclasses.replace(st, clock=st.clock + 1)
-    is_none = st.policy == POLICIES["none"]
     hit = lookup(st, page)
-
-    def on_hit(st: CacheState) -> CacheState:
-        def navis(st):
-            def frozen_touch(st):
-                slot = st.slot_of[page]
-                return dataclasses.replace(
-                    st, frozen_last=st.frozen_last.at[slot].set(st.clock))
-            return jax.lax.cond(st.status[page] == IN_FROZEN, frozen_touch,
-                                lambda s: _navis_hit_window(s, page), st)
-        return jax.lax.cond(st.policy == POLICIES["navis"], navis,
-                            lambda s: _single_region_hit(s, page), st)
-
-    def on_miss(st: CacheState) -> CacheState:
-        def noop(st):
-            return st
-        def admit(st):
-            return jax.lax.cond(st.policy == POLICIES["navis"],
-                                lambda s: _navis_miss(s, page),
-                                lambda s: _single_region_miss(s, page), st)
-        return jax.lax.cond(is_none, noop, admit, st)
-
-    st = jax.lax.cond(hit, on_hit, on_miss, st)
+    if policy == "none":
+        return hit, st
+    st = jax.lax.cond(hit, lambda s: _single_region_hit(s, page),
+                      lambda s: _single_region_miss(s, page), st)
     return hit, st
 
 

@@ -362,7 +362,7 @@ class Engine:
             state.store, spec.lspec, lut, state.codes, state.cache, ctr0,
             entries, pool_size=spec.e_search, beam_width=spec.beam_width,
             max_hops=spec.max_hops, frozen_cache=frozen,
-            visited=spec.visited_impl)
+            cache_policy=spec.cache_policy, visited=spec.visited_impl)
         ctr = res.counters
         dead = (res.pool_ids >= 0) & \
             state.tombstone[jnp.maximum(res.pool_ids, 0)]
@@ -456,7 +456,8 @@ class Engine:
                 s=spec.s_pos, rerank=spec.rerank,
                 beam_width=spec.beam_width, max_hops=spec.max_hops,
                 tombstone=state.tombstone, page_seen=page_seen,
-                visited=spec.visited_impl, new_id=slot)
+                visited=spec.visited_impl, new_id=slot,
+                cache_policy=spec.cache_policy)
             ctr = ires.counters
             if spec.rerank == "full":
                 ctr = self._reclassify(ctr, v, ires.pool_ids, ires.store,
@@ -784,7 +785,8 @@ class Engine:
             return ids, dists, stats, ctr, res.trace
 
         ids, dists, stats, ctrs, traces = jax.vmap(one)(queries)
-        _, cache = cache_mod.apply_traces(state.cache, traces)
+        _, cache = cache_mod.apply_traces(state.cache, traces,
+                                          self.spec.cache_policy)
         state = dataclasses.replace(
             state, cache=cache,
             ctr_search=merge_counters(state.ctr_search,
@@ -884,7 +886,8 @@ class Engine:
         traces = jnp.where(ok[:, None], traces, -1)
 
         # the wave's reads merge into the shared cache in wave order
-        _, cache = cache_mod.apply_traces(state.cache, traces)
+        _, cache = cache_mod.apply_traces(state.cache, traces,
+                                          spec.cache_policy)
 
         # -- phase ②: serialized conflict-aware commits -------------------
         # commits draw reclaimed slots from the free list before fresh
@@ -999,6 +1002,7 @@ class Engine:
                     state.store, spec.lspec, lut, state.codes, state.cache,
                     IOCounters.zeros(), entries, pool_size=pool_size,
                     beam_width=spec.beam_width, max_hops=spec.max_hops,
+                    cache_policy=spec.cache_policy,
                     visited=spec.visited_impl)
                 return res.pool_ids
             return jax.lax.map(one, queries, batch_size=16)

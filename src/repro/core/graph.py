@@ -155,7 +155,7 @@ def _build_block(store: GraphStore, spec: LayoutSpec, block_vecs: jax.Array,
         res = search_mod.disk_traverse(
             store, spec, lut, codes, dummy_cache, IOCounters.zeros(),
             entry_ids, pool_size=e_pos, beam_width=beam_width,
-            max_hops=max_hops)
+            max_hops=max_hops, cache_policy="none")
         # exact rerank against the build vectors (offline: vectors on hand)
         valid = res.pool_ids >= 0
         d = jnp.where(valid, pq_mod.exact_l2(
@@ -197,7 +197,7 @@ def _refine_block(store: GraphStore, spec: LayoutSpec, ids_block: jax.Array,
         res = search_mod.disk_traverse(
             store, spec, lut, codes, dummy_cache, IOCounters.zeros(),
             entry_ids, pool_size=e_pos, beam_width=beam_width,
-            max_hops=max_hops)
+            max_hops=max_hops, cache_policy="none")
         cand = jnp.concatenate([res.pool_ids, store.edges[i]])
         # dedupe (first occurrence) + exclude self
         arange = jnp.arange(cand.shape[0], dtype=jnp.int32)

@@ -340,6 +340,7 @@ def position_seek(store: GraphStore, spec: LayoutSpec, codec: pq_mod.PQCodec,
                   max_hops: int = 512, tombstone: jax.Array | None = None,
                   page_seen: jax.Array | None = None,
                   frozen_cache: bool = False,
+                  cache_policy: str = "navis",
                   visited: str = "hash") -> SeekResult:
     """① Position seeking: traverse + rerank + neighbor selection, no
     structural mutation.  Pure in the engine state, so a whole insert wave
@@ -347,12 +348,15 @@ def position_seek(store: GraphStore, spec: LayoutSpec, codec: pq_mod.PQCodec,
     probes the cache snapshot and records its page-access trace, exactly
     like the search fan-out).  ``visited`` picks the traversal's visited
     sets — "hash" keeps per-seek state independent of the corpus, so an
-    insert wave's memory is bounded by the frontier, not ``n_max``."""
+    insert wave's memory is bounded by the frontier, not ``n_max``.
+    ``cache_policy``: the cache's policy, static, for the threaded
+    (unfrozen) traversal's accesses."""
     lut = pq_mod.adc_lut(codec, new_vec)
     res = search_mod.disk_traverse(
         store, spec, lut, codes, cache, counters, entry_ids,
         pool_size=e_pos, beam_width=beam_width, max_hops=max_hops,
-        page_seen=page_seen, frozen_cache=frozen_cache, visited=visited)
+        page_seen=page_seen, frozen_cache=frozen_cache,
+        cache_policy=cache_policy, visited=visited)
     counters = res.counters
     cache = res.cache
     pool_ids = res.pool_ids
@@ -407,7 +411,8 @@ def insert_vertex(store: GraphStore, spec: LayoutSpec, codec: pq_mod.PQCodec,
                   tombstone: jax.Array | None = None,
                   page_seen: jax.Array | None = None,
                   visited: str = "hash",
-                  new_id: jax.Array | None = None) -> InsertResult:
+                  new_id: jax.Array | None = None,
+                  cache_policy: str = "navis") -> InsertResult:
     """One in-place insertion.  ``rerank``: "casr" | "full" (static).
 
     The caller encodes the new vector into the target slot of ``codes``
@@ -415,13 +420,14 @@ def insert_vertex(store: GraphStore, spec: LayoutSpec, codec: pq_mod.PQCodec,
     synchronously).  ``tombstone`` masks deleted vertices out of neighbor
     selection; ``page_seen`` seeds the traversal's page buffer (bulk
     merges); ``new_id`` commits into a reclaimed slot instead of
-    appending at ``store.count`` (free-list reuse).
+    appending at ``store.count`` (free-list reuse); ``cache_policy`` is
+    the cache's policy (static).
     """
     seek = position_seek(
         store, spec, codec, codes, cache, counters, new_vec, entry_ids,
         e_pos=e_pos, k=k, s=s, rerank=rerank, beam_width=beam_width,
         max_hops=max_hops, tombstone=tombstone, page_seen=page_seen,
-        visited=visited)
+        cache_policy=cache_policy, visited=visited)
     nid = (store.count if new_id is None else new_id).astype(jnp.int32)
     sres = commit_insert(store, spec, seek.cache, seek.counters, new_vec,
                          seek.nbrs, codes, sym_tables, new_id=nid)

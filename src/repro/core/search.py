@@ -168,7 +168,8 @@ def fetch_edgelists(store: GraphStore, spec: LayoutSpec,
                     page_seen: visited_mod.VisitedSet, ids: jax.Array,
                     valid: jax.Array,
                     trace: jax.Array | None = None,
-                    trace_n: jax.Array | None = None):
+                    trace_n: jax.Array | None = None,
+                    cache_policy: str = "navis"):
     """Read the edge pages backing ``ids`` (beam of W vertices) through the
     per-query buffer (``page_seen``, a visited set) and the host cache.
     Pages already read by *this* traversal are free (the query holds them in
@@ -182,7 +183,8 @@ def fetch_edgelists(store: GraphStore, spec: LayoutSpec,
     snapshot*: hits come from :func:`cache_mod.lookup` (pure), the cache is
     returned untouched, and every charged access is appended to ``trace``
     for later :func:`cache_mod.apply_trace` replay.  This is the read path
-    concurrent (vmapped) searches share.
+    concurrent (vmapped) searches share.  Otherwise each charged access
+    runs :func:`cache_mod.access` under the static ``cache_policy``.
 
     Returns (edges [W,R], cache, counters, page_seen, trace, trace_n).
     """
@@ -230,7 +232,7 @@ def fetch_edgelists(store: GraphStore, spec: LayoutSpec,
 
             def charged(args):
                 cache_c, counters = args
-                hit, cache_c = cache_mod.access(cache_c, page)
+                hit, cache_c = cache_mod.access(cache_c, page, cache_policy)
                 return cache_c, _charge_access(counters, spec, hit)
 
             cache_c, counters = lax.cond(dup, lambda a: a, charged,
@@ -312,6 +314,7 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
                   max_hops: int = 512,
                   page_seen=None,
                   frozen_cache: bool = False,
+                  cache_policy: str = "navis",
                   visited: str = "hash",
                   visited_capacity: int | None = None) -> TraverseResult:
     """Greedy beam search over the on-disk graph with PQ distances.
@@ -330,6 +333,8 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
     ordered replay into the shared cache afterwards.  Both fan-outs ride
     on this: ``search_many`` (|E_search| pools) and ``insert_many``'s
     position-seek phase (|E_pos| pools via :func:`insert.position_seek`).
+    Otherwise the cache threads through the loop, each access under the
+    static ``cache_policy`` (the policy the cache was built with).
 
     Which pool entries were expanded is a flag on each pool slot, moved
     with the slot by the merge (see the comment in the loop), so the
@@ -397,7 +402,8 @@ def disk_traverse(store: GraphStore, spec: LayoutSpec, lut: jax.Array,
         with jax.named_scope("navis.fetch"):
             edges, cache_out, counters, ps, trace, trace_n = \
                 fetch_edgelists(store, spec, cache_in, counters, ps,
-                                beam, beam_valid, trace, trace_n)
+                                beam, beam_valid, trace, trace_n,
+                                cache_policy)
             if spec.kind == "packed":
                 vec_loaded = visited_mod.add(vec_loaded, beam, beam_valid)
 

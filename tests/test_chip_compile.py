@@ -197,3 +197,50 @@ def test_text768_adc_has_no_lut_gather(name, wave, text_engine,
                                        text_compiled):
     assert not _gathers_lut(text_compiled(name), wave,
                             text_engine.spec.pq_m), name
+
+
+def _replay_loop_copies(text: str, p_max: int) -> list[str]:
+    """Copies of a ``[p_max]`` array inside the innermost loop of the
+    cache-trace replay (scope ``navis.cache_replay``) of compiled HLO
+    ``text``, and in every computation that loop calls."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+
+    def called(name, seen):
+        if name in comps and name not in seen:
+            seen.add(name)
+            for line in comps[name]:
+                refs = re.findall(
+                    r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line)
+                for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                        line):
+                    refs += re.findall(r"%([\w.\-]+)", group)
+                for ref in refs:
+                    called(ref, seen)
+        return seen
+
+    bodies = [re.search(r"body=%([\w.\-]+)", line).group(1)
+              for lines in comps.values() for line in lines
+              if " while(" in line and "navis.cache_replay" in line]
+    inner = [b for b in bodies if not any(" while(" in line
+                                          for line in comps[b])]
+    assert inner, bodies
+    wide = re.compile(r"= \w+\[%d\]\S* copy(-start)?\(" % p_max)
+    return [line.strip()[:120] for b in inner for c in called(b, set())
+            for line in comps[c] if wide.search(line)]
+
+
+@pytest.mark.parametrize("name", ["search_many", "insert_many"])
+def test_cache_replay_loop_copies_no_page_table(name, engine, one_chip,
+                                                compiled):
+    """Each replayed access updates the ``[p_max]`` page tables in place:
+    the v5e compile of the replay's loop copies none of them (a copy there
+    is a pass over the table on every access)."""
+    p_max = _programs(engine, one_chip)[name][1][0].cache.status.shape[0]
+    copies = _replay_loop_copies(compiled(name).as_text(), p_max)
+    assert not copies, copies

@@ -304,11 +304,11 @@ def test_priority_admit_pins_into_frozen():
     assert int(st_.status[7]) == 2                     # IN_FROZEN
     slot = int(st_.slot_of[7])
     assert int(st_.frozen_pages[slot]) == 7
-    hit, _ = cache_mod.access(st_, jnp.int32(7))
+    hit, _ = cache_mod.access(st_, jnp.int32(7), "navis")
     assert bool(hit)
     # a page sitting in the window is moved, not duplicated
     st_ = cache_mod.init_cache(128, 20, "navis", jax.random.PRNGKey(0))
-    _, st_ = cache_mod.access(st_, jnp.int32(9))       # miss -> window
+    _, st_ = cache_mod.access(st_, jnp.int32(9), "navis")  # -> window
     st_ = cache_mod.priority_admit(st_, jnp.int32(9))
     assert int(st_.status[9]) == 2
     assert int((st_.window_pages == 9).sum()) == 0

@@ -4,6 +4,7 @@ sequential scan, per preset, is in test_presets.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import Engine, IOCounters, preset
 from repro.core import cache as cache_mod
@@ -68,7 +69,7 @@ def test_search_many_warms_shared_cache(navis, dataset):
 
 def test_lookup_is_pure_and_matches_access():
     st = cache_mod.init_cache(64, 8, "lru", jax.random.PRNGKey(0))
-    _, st = cache_mod.access(st, jnp.int32(3))
+    _, st = cache_mod.access(st, jnp.int32(3), "lru")
     before = jax.tree.leaves(st)
     assert bool(cache_mod.lookup(st, jnp.int32(3)))
     assert not bool(cache_mod.lookup(st, jnp.int32(4)))
@@ -76,15 +77,16 @@ def test_lookup_is_pure_and_matches_access():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_apply_trace_equals_sequential_access():
+@pytest.mark.parametrize("policy", sorted(cache_mod.POLICIES))
+def test_apply_trace_equals_sequential_access(policy):
     pages = [5, 9, 5, 2, 9, 5]
-    st0 = cache_mod.init_cache(64, 8, "navis", jax.random.PRNGKey(1))
+    st0 = cache_mod.init_cache(64, 8, policy, jax.random.PRNGKey(1))
     st_seq, hits_seq = st0, 0
     for p in pages:
-        h, st_seq = cache_mod.access(st_seq, jnp.int32(p))
+        h, st_seq = cache_mod.access(st_seq, jnp.int32(p), policy)
         hits_seq += int(h)
     trace = jnp.asarray(pages + [-1, -1], jnp.int32)   # -1 padding skipped
-    hits_rep, st_rep = cache_mod.apply_trace(st0, trace)
+    hits_rep, st_rep = cache_mod.apply_trace(st0, trace, policy)
     assert int(hits_rep) == hits_seq
     for a, b in zip(jax.tree.leaves(st_seq), jax.tree.leaves(st_rep)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
