@@ -47,21 +47,25 @@ def main(argv=None) -> int:
     cfg = run.load_json("configs", cell["config"])
     mix = run.load_json("mixes", cell["traffic"])
     k = cfg["engine"]["k"]
+    drv = run.load_driver(cfg)
     base, cents = jax.block_until_ready(corpus(cfg))
-    engine, restored, _ = run.open_index(cell["config"], cfg, base)
+    handle, restored, _ = drv.open(
+        cell["config"], cfg, base, chips=cell["chips"],
+        cache=run.BENCH / ".index_cache", root=run.ROOT)
     for seed in args.seeds:
         traffic = Traffic(cfg, mix, seed, cents)
-        run.warm(engine, restored, traffic)
-        state, waves, elapsed = run.run_window(engine, restored, traffic,
-                                               mix, args.seconds)
-        count = int(state.store.count)
+        run.warm(drv, handle, restored, traffic)
+        state, waves, elapsed = run.run_window(drv, handle, restored,
+                                               traffic, mix, args.seconds)
+        count = drv.count(handle, state)
         run.fetch(waves)
         timed = list(waves)
-        waves.append(run.read_back(engine, state, traffic, timed))
+        waves.append(run.read_back(drv, handle, state, traffic, timed))
         del state
         # the insert commit left out: the read-back finds the state the
         # window started from
-        unchanged = timed + [run.read_back(engine, restored, traffic, timed)]
+        unchanged = timed + [run.read_back(drv, handle, restored, traffic,
+                                           timed)]
         sound = run.compare(cfg, base, traffic, waves, count)
         ctrl = run.compare(cfg, base, traffic, waves, count,
                            answer=lambda q, c, live: reference.topk_bf16(

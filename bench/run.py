@@ -4,19 +4,21 @@
 
 A cell (``BENCHMARK.json``'s ``workloads``) names a deployment
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/mixes/<traffic>.json``).  A run:
+(``bench/mixes/<traffic>.json``); the deployment names the driver that
+runs its engine (``bench/drivers/<driver>.py``, ``single`` when it names
+none; the contract is in ``drivers/__init__.py``).  A run:
 
 1. loads them by name, and the readers of the cell's metrics;
-2. restores the deployment's index, or builds and saves it on the first
-   run in this checkout (``index_cache.py``);
+2. has the driver restore the deployment's index on the cell's chips, or
+   build and save it on the first run in this checkout
+   (``index_cache.py``);
 3. makes the run's traffic on the device from ``--seed`` (``traffic.py``);
 4. warms every program and state signature the window uses, and discards
    what the warm-up returns;
-5. drives a closed loop of rounds (insert waves through
-   ``Engine.insert_many``, then search waves through
-   ``Engine.search_many``, one ``EngineState`` threaded through) until
-   ``--seconds`` have passed, timing every wave from dispatch to
-   ``block_until_ready``;
+5. drives a closed loop of rounds (insert waves through the driver's
+   ``insert``, then search waves through its ``search``, one state
+   threaded through) until ``--seconds`` have passed, timing every wave
+   from dispatch to ``block_until_ready``;
 6. after the window, looks every acknowledged insert up by a copy of
    itself on the window's final state (``read_back``), reads the
    device's peak memory,
@@ -52,7 +54,7 @@ TRACE_S = 10.0
 
 
 class UnknownName(LookupError):
-    """A cell, configuration, mix or metric that has no file."""
+    """A cell, configuration, mix, driver or metric that has no file."""
 
 
 def log(name: str, value) -> None:
@@ -88,16 +90,30 @@ def load_json(kind: str, name: str, bench: pathlib.Path = BENCH) -> dict:
     return json.loads(path.read_text())
 
 
+def _load_module(kind: str, path: pathlib.Path):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{path.stem.replace('.', '_')}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def load_reader(kind: str, name: str, bench: pathlib.Path = BENCH):
     """``read(rec, trace)`` of ``bench/<kind>/<name>.py``."""
     path = bench / kind / f"{name}.py"
     if not path.is_file():
         raise UnknownName(f"no reader for metric {name!r} ({path})")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load_module(kind, path).read
+
+
+def load_driver(cfg: dict, bench: pathlib.Path = BENCH):
+    """The module of the driver that configuration ``cfg`` names."""
+    from bench import drivers
+
+    path = drivers.path(bench, cfg)
+    if not path.is_file():
+        raise UnknownName(f"no driver named {path.stem!r} ({path})")
+    return _load_module("drivers", path)
 
 
 def cell_metrics(bm: dict, section: str, cell: str) -> list[dict]:
@@ -106,49 +122,9 @@ def cell_metrics(bm: dict, section: str, cell: str) -> list[dict]:
             if cell in m.get("workloads", [cell])]
 
 
-# -- the deployment -----------------------------------------------------------
-
-def engine_spec(cfg: dict):
-    from repro.core import preset
-
-    eng = dict(cfg["engine"])
-    name = eng.pop("preset")
-    return preset(name, dim=cfg["dim"],
-                  n_max=cfg["n_base"] + cfg["headroom"], **eng)
-
-
-def open_index(name: str, cfg: dict, base, **cache_kw):
-    """(engine, state, build seconds or None): the saved index, or one
-    built now with ``Engine.build`` and saved for later runs."""
-    import jax
-
-    from bench import index_cache
-    from repro.core import Engine
-
-    engine = Engine(engine_spec(cfg))
-    t = time.perf_counter()
-    state = index_cache.restore(name, engine, base, **cache_kw)
-    if state is not None:
-        jax.block_until_ready(state)
-        log("index_restore_s", time.perf_counter() - t)
-        return engine, state, None
-    b = cfg["build"]
-    t = time.perf_counter()
-    state = jax.block_until_ready(engine.build(
-        jax.random.PRNGKey(cfg["data_seed"]), base,
-        build_block=b["build_block"], build_e_pos=b["build_e_pos"],
-        alpha=b["alpha"]))
-    build_s = time.perf_counter() - t
-    log("build_s", build_s)
-    log("build_vectors_per_s", cfg["n_base"] / build_s)
-    index_cache.save(name, engine, state, base, **cache_kw)
-    log("index_save_s", time.perf_counter() - t - build_s)
-    return engine, state, build_s
-
-
 # -- set-up, window -----------------------------------------------------------
 
-def warm(engine, state, traffic) -> None:
+def warm(drv, handle, state, traffic) -> None:
     """Run each program on each state signature the window gives it
     (restored, after an insert wave, after a search wave); discard all."""
     import jax
@@ -158,13 +134,13 @@ def warm(engine, state, traffic) -> None:
     s = state
     for op in ("insert", "insert", "search", "insert", "search"):
         if op == "insert":
-            out = engine.insert_many(s, v)
+            out = drv.insert(handle, s, v)
             s = out[1]
         else:
-            out = engine.search_many(s, q)
+            out = drv.search(handle, s, q)
             s = out[3]
         jax.block_until_ready(out)
-    jax.block_until_ready(engine.search_many(state, q))
+    jax.block_until_ready(drv.search(handle, state, q))
 
 
 def _annotate(tracing: bool):
@@ -175,14 +151,7 @@ def _annotate(tracing: bool):
     return jax.profiler.TraceAnnotation
 
 
-def _counters(ctr) -> dict:
-    import dataclasses
-
-    return {f.name: int(getattr(ctr, f.name))
-            for f in dataclasses.fields(ctr)}
-
-
-def run_window(engine, state, traffic, mix: dict, seconds: float, *,
+def run_window(drv, handle, state, traffic, mix: dict, seconds: float, *,
                tracing: bool = False, first: int = 0):
     """The closed loop: rounds ``first``, ``first + 1``, … until
     ``seconds`` have passed, at least one.  Returns (state, waves,
@@ -192,7 +161,6 @@ def run_window(engine, state, traffic, mix: dict, seconds: float, *,
     import jax
 
     note = _annotate(tracing)
-    search, insert = engine.search_many, engine.insert_many
     waves = []
     t0 = time.perf_counter()
     r = first
@@ -205,28 +173,28 @@ def run_window(engine, state, traffic, mix: dict, seconds: float, *,
                     v = ins[w]
                 t = time.perf_counter()
                 with note("dispatch:insert_many"):
-                    stats, state = insert(state, v)
+                    dropped, state = drv.insert(handle, state, v)
                 with note("wait:insert_many"):
-                    jax.block_until_ready((stats, state))
+                    jax.block_until_ready((dropped, state))
                 wall = time.perf_counter() - t
                 with note("record"):
                     waves.append(dict(kind="insert", round=r, wave=w,
                                       wall_s=wall, n=v.shape[0],
-                                      dropped=stats.dropped))
+                                      dropped=dropped))
             for w in range(mix["search_waves"]):
                 with note("traffic"):
                     q = qs[w]
                 t = time.perf_counter()
                 with note("dispatch:search_many"):
-                    ids, dists, stats, state = search(state, q)
+                    ids, dists, rounds, state = drv.search(handle, state, q)
                 with note("wait:search_many"):
-                    jax.block_until_ready((ids, dists, stats, state))
+                    jax.block_until_ready((ids, dists, rounds, state))
                 wall = time.perf_counter() - t
                 with note("record"):
                     waves.append(dict(kind="search", round=r, wave=w,
                                       wall_s=wall, n=q.shape[0],
                                       ids=ids, dists=dists,
-                                      rounds=stats.serial_rounds))
+                                      rounds=rounds))
             r += 1
     return state, waves, time.perf_counter() - t0
 
@@ -243,7 +211,7 @@ def fetch(waves: list[dict]) -> None:
 
 def acknowledged(traffic, waves: list[dict]):
     """The inserts the window acknowledged, in the order of their
-    acknowledgement, which is the order in which the engine numbers new
+    acknowledgement, which is the order in which every driver numbers new
     vectors (``n_base``, ``n_base + 1``, …).  Needs fetched waves."""
     import jax.numpy as jnp
     import numpy as np
@@ -258,10 +226,10 @@ def acknowledged(traffic, waves: list[dict]):
     return jnp.concatenate(parts) if parts else jnp.zeros((0, dim))
 
 
-def read_back(engine, state, traffic, waves: list[dict]) -> dict:
+def read_back(drv, handle, state, traffic, waves: list[dict]) -> dict:
     """After the window, every acknowledged insert looked up by a copy of
-    itself, in search waves of the window's size through the window's
-    own ``search_many`` on ``state`` (the window's final state): each is
+    itself, in search waves of the window's size through the driver's
+    ``search`` on ``state`` (the window's final state): each is
     its own nearest neighbour.  Returns the lookups as a wave of kind
     ``readback`` (``picks``: which acknowledged inserts, in the order of
     their acknowledgement); the states the waves return are discarded.
@@ -278,11 +246,11 @@ def read_back(engine, state, traffic, waves: list[dict]) -> dict:
         # a short last wave is filled with the first inserts again
         rows = jnp.asarray(np.concatenate(
             [picks[i:i + size], np.resize(picks, size)])[:size])
-        out = engine.search_many(state, inserted[rows])
+        out = drv.search(handle, state, inserted[rows])
         m = min(size, n - i)
         ids.append(np.asarray(out[0])[:m])
         dists.append(np.asarray(out[1])[:m])
-    k = engine.spec.k
+    k = drv.k(handle)
     return dict(kind="readback", picks=picks,
                 ids=np.concatenate(ids) if ids else np.zeros((0, k), int),
                 dists=np.concatenate(dists) if dists
@@ -459,15 +427,16 @@ def run(workload: str, seed: int, seconds: float, tracing: bool, *,
     devs = jax.devices()
     log("device", f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}")
 
+    drv = load_driver(cfg, bench)
     base, cents = jax.block_until_ready(corpus(cfg))
-    engine, state, build_s = open_index(
-        cell["config"], cfg, base, cache=bench / ".index_cache", root=root)
+    handle, state, build_s = drv.open(
+        cell["config"], cfg, base, chips=cell["chips"],
+        cache=bench / ".index_cache", root=root)
     traffic = Traffic(cfg, mix, seed, cents)
     jax.block_until_ready(traffic.chunk(0))
-    warm(engine, state, traffic)
-    ctr0 = (_counters(state.ctr_search), _counters(state.ctr_insert))
-    sizes = (engine.search_many._cache_size(),
-             engine.insert_many._cache_size())
+    warm(drv, handle, state, traffic)
+    ctr0 = drv.counters(handle, state)
+    compiled = drv.compiled(handle)
     trace_dir = out / "trace" / cell["name"]
     if tracing:
         import shutil
@@ -479,31 +448,28 @@ def run(workload: str, seed: int, seconds: float, tracing: bool, *,
     # a traced run records the first TRACE_S of its window; the rest runs
     # untraced, and its answers are compared as well
     state, waves, elapsed = run_window(
-        engine, state, traffic, mix,
+        drv, handle, state, traffic, mix,
         min(seconds, TRACE_S) if tracing else seconds, tracing=tracing)
-    ctr = {"search": _counters(state.ctr_search),
-           "insert": _counters(state.ctr_insert)}
+    ctr = drv.counters(handle, state)
     measured = len(waves)
     if tracing:
         jax.profiler.stop_trace()
         log("traced_s", elapsed)
         if elapsed < seconds:
             state, rest, more = run_window(
-                engine, state, traffic, mix, seconds - elapsed,
+                drv, handle, state, traffic, mix, seconds - elapsed,
                 first=waves[-1]["round"] + 1)
             waves += rest
             elapsed += more
     log("window_s", elapsed)
-    new_sizes = (engine.search_many._cache_size(),
-                 engine.insert_many._cache_size())
-    log("compiles_in_window", sum(new_sizes) - sum(sizes))
-    final_count = int(state.store.count)
+    log("compiles_in_window", drv.compiled(handle) - compiled)
+    final_count = drv.count(handle, state)
     # the counters are int32 on the device and may wrap; a window's
     # increments stay far below 2**32
-    ctr = {side: {k: (v - c0[k]) % 2 ** 32 for k, v in ctr[side].items()}
-           for side, c0 in zip(("search", "insert"), ctr0)}
+    ctr = {side: {k: (v - ctr0[side][k]) % 2 ** 32
+                  for k, v in ctr[side].items()} for side in ctr}
     fetch(waves)
-    waves.append(read_back(engine, state, traffic, waves))
+    waves.append(read_back(drv, handle, state, traffic, waves))
     device = device_info()
     del state
     summary = None

@@ -2,11 +2,13 @@
 
 The tiny cell (``tiny.py``) runs through the harness's own phases: the
 index is built, saved and restored, a window of one round drives
-``Engine.insert_many`` and ``Engine.search_many``, and the answers are
-compared with the plain reference.  The timed path is then broken
-underneath in each way the benchmark's cells can break, and the control
-(the reference in bfloat16) takes the engine's place: each must come out
-not correct.
+``Engine.insert_many`` and ``Engine.search_many`` through the ``single``
+driver, and the answers are compared with the plain reference.  The timed
+path is then broken underneath in each way the benchmark's cells can
+break, and the control (the reference in bfloat16) takes the engine's
+place: each must come out not correct.  A test-only driver over four
+virtual devices (``four_shards.py``) shows that the seam is not shaped
+for one chip.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import os
 import pathlib
 import subprocess
 import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +35,8 @@ from bench.traffic import CHUNK_ROUNDS, Traffic, make_chunk  # noqa: E402
 from repro.core import Engine  # noqa: E402
 
 BM = json.loads((ROOT / "BENCHMARK.json").read_text())
+SINGLE = run.load_driver({})
+CONTRACT = ("open", "insert", "search", "count", "counters", "compiled", "k")
 KIND = "TPU v5 lite"          # whose peaks the per-layer readers see
 DATA = BENCH / "tests" / "data"
 
@@ -56,7 +61,7 @@ def checkout(tmp_path_factory):
 def test_config_and_mix_found_by_name(kind, name):
     got = run.load_json(kind, name)
     if kind == "configs":
-        spec = run.engine_spec(got)
+        spec = SINGLE.engine_spec(got)
         assert spec.dim == got["dim"]
         assert spec.n_max == got["n_base"] + got["headroom"]
         assert set(got["limits"]) <= {"search_miss", "readback_miss",
@@ -73,15 +78,33 @@ def test_metric_reader_found_by_name(section, name):
     assert callable(run.load_reader(kind, name))
 
 
-@pytest.mark.parametrize("what", ["cell", "configs", "mixes", "reader"])
+@pytest.mark.parametrize("what", ["cell", "configs", "mixes", "reader",
+                                  "driver"])
 def test_unknown_name_is_an_error(what):
     with pytest.raises(run.UnknownName):
         if what == "cell":
             run.find_cell(BM, "no.such.cell")
         elif what == "reader":
             run.load_reader("layer_metrics", "no_such_metric")
+        elif what == "driver":
+            run.load_driver({"driver": "no_such_driver"})
         else:
             run.load_json(what, "no_such_name")
+
+
+def test_driver_found_by_name():
+    drv = run.load_driver({"driver": "single"})
+    assert pathlib.Path(drv.__file__) == BENCH / "drivers" / "single.py"
+    assert all(callable(getattr(drv, f)) for f in CONTRACT)
+
+
+@pytest.mark.parametrize("name", sorted(
+    {w["config"] for w in BM["workloads"]} | {"tiny"}))
+def test_config_without_driver_runs_single(name):
+    cfg = CONFIG if name == "tiny" else run.load_json("configs", name)
+    assert "driver" not in cfg
+    drv = run.load_driver(cfg)
+    assert pathlib.Path(drv.__file__) == BENCH / "drivers" / "single.py"
 
 
 # -- the reference ------------------------------------------------------------
@@ -156,10 +179,10 @@ def test_index_restore_equals_build(checkout):
     cfg = CONFIG
     base, cents = corpus(cfg)
     cache = root / "bench" / ".index_cache"
-    eng, restored, build_s = run.open_index("tiny", cfg, base, cache=cache,
-                                            root=root)
+    eng, restored, build_s = SINGLE.open("tiny", cfg, base, chips=1,
+                                         cache=cache, root=root)
     assert build_s is None
-    fresh_eng = Engine(run.engine_spec(cfg))
+    fresh_eng = Engine(SINGLE.engine_spec(cfg))
     b = cfg["build"]
     built = fresh_eng.build(jax.random.PRNGKey(cfg["data_seed"]), base,
                             build_block=b["build_block"],
@@ -172,17 +195,55 @@ def test_index_restore_equals_build(checkout):
         np.asarray(fresh_eng.search_many(built, q)[0]),
         np.asarray(eng.search_many(restored, q)[0]))
 
-    # a change to the program's source misses the saved index
-    src = root / "src" / "repro" / "core" / "__init__.py"
-    text = src.read_text()
-    try:
-        src.write_text(text + "\n# changed\n")
-        assert index_cache.restore("tiny", Engine(run.engine_spec(cfg)),
-                                   base, cache=cache, root=root) is None
-    finally:
-        src.write_text(text)
-    assert index_cache.restore("tiny", Engine(run.engine_spec(cfg)), base,
-                               cache=cache, root=root) is not None
+    # a change to the program's source, or to the driver, misses the
+    # saved index
+    for src in (root / "src" / "repro" / "core" / "__init__.py",
+                root / "bench" / "drivers" / "single.py"):
+        text = src.read_text()
+        try:
+            src.write_text(text + "\n# changed\n")
+            assert index_cache.restore("tiny", base, cache=cache,
+                                       root=root) is None
+        finally:
+            src.write_text(text)
+    assert index_cache.restore("tiny", base, cache=cache,
+                               root=root) is not None
+
+
+def test_single_waves_equal_the_engine_loop(checkout):
+    """Three rounds through the ``single`` driver give the ids,
+    distances, refusals, rounds and state of a direct loop of
+    ``Engine.insert_many`` / ``Engine.search_many``, bit for bit."""
+    root, _ = checkout
+    cfg, bench = CONFIG, root / "bench"
+    mix = run.load_json("mixes", "tiny", bench)
+    base, cents = corpus(cfg)
+    eng, restored, _ = SINGLE.open("tiny", cfg, base, chips=1,
+                                   cache=bench / ".index_cache", root=root)
+    traffic = Traffic(cfg, mix, 15, cents)
+    state, waves = restored, []
+    for r in range(3):
+        state, more, _ = run.run_window(SINGLE, eng, state, traffic, mix,
+                                        0.0, first=r)
+        waves += more
+    direct, want = restored, []
+    for r in range(3):
+        ins, qs = traffic.round(r)
+        for w in range(mix["insert_waves"]):
+            stats, direct = eng.insert_many(direct, ins[w])
+            want.append({"dropped": stats.dropped})
+        for w in range(mix["search_waves"]):
+            ids, dists, stats, direct = eng.search_many(direct, qs[w])
+            want.append({"ids": ids, "dists": dists,
+                         "rounds": stats.serial_rounds})
+    assert len(waves) == len(want) == 3 * (mix["insert_waves"]
+                                           + mix["search_waves"])
+    for got, w in zip(waves, want):
+        for key, x in w.items():
+            np.testing.assert_array_equal(np.asarray(got[key]),
+                                          np.asarray(x))
+    for x, y in zip(jax.tree.leaves(state), jax.tree.leaves(direct)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # -- a run, sound and broken --------------------------------------------------
@@ -249,14 +310,14 @@ def test_control_is_not_correct(checkout):
     cfg = CONFIG
     mix = run.load_json("mixes", "tiny", root / "bench")
     base, cents = corpus(cfg)
-    eng, state, _ = run.open_index("tiny", cfg, base,
-                                   cache=root / "bench" / ".index_cache",
-                                   root=root)
+    eng, state, _ = SINGLE.open("tiny", cfg, base, chips=1,
+                                cache=root / "bench" / ".index_cache",
+                                root=root)
     traffic = Traffic(cfg, mix, 14, cents)
-    state, waves, _ = run.run_window(eng, state, traffic, mix, 1e-3)
-    count = int(state.store.count)
+    state, waves, _ = run.run_window(SINGLE, eng, state, traffic, mix, 1e-3)
+    count = SINGLE.count(eng, state)
     run.fetch(waves)
-    waves.append(run.read_back(eng, state, traffic, waves))
+    waves.append(run.read_back(SINGLE, eng, state, traffic, waves))
     sound = run.compare(cfg, base, traffic, waves, count)
     assert run.judge(sound, cfg["limits"])[0], sound
     ctrl = run.compare(cfg, base, traffic, waves, count,
@@ -264,6 +325,90 @@ def test_control_is_not_correct(checkout):
                            q, c, live, k=cfg["engine"]["k"]))
     assert not run.judge(ctrl, cfg["limits"])[0], ctrl
     assert ctrl["dist_rel_err"] > cfg["limits"]["dist_rel_err"]
+
+
+# -- the seam on four devices --------------------------------------------------
+
+_FOUR = textwrap.dedent("""
+    import json, pathlib, sys
+    repo, dest = (pathlib.Path(a) for a in sys.argv[1:3])
+    sys.path[:0] = [str(repo), str(repo / "src")]
+    import jax
+    from bench import run
+    from bench.corpus import corpus
+    from bench.tests.tiny import CONFIG, make_checkout
+
+    root = make_checkout(dest)
+    bench = root / "bench"
+    # the runs' engines compile the same programs: the later runs read
+    # them from a compilation cache in the checkout
+    jax.config.update("jax_compilation_cache_dir", str(root / "jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    text = (repo / "bench" / "tests" / "four_shards.py").read_text()
+    mapped = "g[np.maximum(i, 0)]"
+    assert text.count(mapped) == 1
+    bm = json.loads((root / "BENCHMARK.json").read_text())
+    for name, src in (("four", text),
+                      ("shifted", text.replace(mapped, mapped + " + 1"))):
+        (bench / "drivers" / f"{name}.py").write_text(src)
+        (bench / "configs" / f"{name}.json").write_text(
+            json.dumps({**CONFIG, "driver": name}))
+        bm["workloads"].append({"name": f"{name}.mixed", "config": name,
+                                "traffic": "tiny", "chips": 4,
+                                "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
+
+    out = {"devices": len(jax.devices())}
+    for cell, seed in (("four.mixed", 31), ("four.mixed", 32),
+                       ("shifted.mixed", 33)):
+        res = run.run(cell, seed, 1e-3, False, kind="TPU v5 lite",
+                      root=root)
+        saved = json.loads((bench / "out" / f"{cell}-{seed}.json")
+                           .read_text())
+        out[f"{cell}-{seed}"] = {"correct": res["correct"],
+                                 "numbers": saved["numbers"],
+                                 "build_s": saved["record"]["build_s"]}
+    cfg = run.load_json("configs", "four", bench)
+    drv = run.load_driver(cfg, bench)
+    base, _ = corpus(cfg)
+    h, state, _ = drv.open("four", cfg, base, chips=4,
+                           cache=bench / ".index_cache", root=root)
+    out["shard_devices"] = [sorted({str(d) for x in jax.tree.leaves(s)
+                                    for d in x.devices()})
+                            for s in state["shards"]]
+    out["count"] = drv.count(h, state)
+    print(json.dumps(out))
+""")
+
+
+def test_seam_drives_four_shards_on_four_devices(tmp_path):
+    """A test-only driver (``four_shards.py``) keeps one shard on each of
+    four virtual devices, routes inserts and maps shard-local ids to the
+    harness's numbering: its runs are correct, the first built and the
+    next restored onto the four devices; a copy whose id map is shifted by
+    one is not correct."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", _FOUR, str(ROOT),
+                        str(tmp_path)], env=env, capture_output=True,
+                       text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4
+    built, restored = out["four.mixed-31"], out["four.mixed-32"]
+    assert built["build_s"] is not None and restored["build_s"] is None
+    for r in (built, restored):
+        assert r["correct"] is True, r["numbers"]
+        # the shards' count is the base and every acknowledged insert
+        assert r["numbers"]["count_gap"] == 0
+        assert r["numbers"]["dropped"] == 0
+    devices = out["shard_devices"]
+    assert all(len(d) == 1 for d in devices)
+    assert len({d[0] for d in devices}) == 4
+    assert out["count"] == CONFIG["n_base"]
+    shifted = out["shifted.mixed-33"]
+    assert shifted["correct"] is False, shifted["numbers"]
 
 
 def test_no_tpu_means_no_result(tmp_path):
